@@ -1,7 +1,8 @@
 """Paper Fig. 5: SpMV runtime — no overlap vs overlapped communication.
 
-Runs the pipelined SpMV of the heterogeneous execution engine (8 simulated
-shards in a subprocess, cage15-like band matrix) in its two schedules:
+Runs the pipelined SpMV of the heterogeneous execution engine (cage15-like
+band matrix; on a TPU over this process's chips, elsewhere over 8 forced
+host devices in a child process) in its two schedules:
   * overlap=False — "No Overlap": optimization barrier forces the halo
     exchange to complete before local compute starts;
   * overlap=True  — "GHOST task mode": local compute is data-independent of
@@ -11,68 +12,91 @@ Also reports the derived quantities that matter at scale: halo volume per
 shard (compressed remote columns, Fig. 3) and the local/remote nnz split."""
 from __future__ import annotations
 
+import json
+import os
 import subprocess
 import sys
-import os
+import time
+
+import numpy as np
 
 from benchmarks.common import policy_row, row
 
-CODE = r"""
-import time, numpy as np, jax
-from jax.sharding import Mesh
-from repro.matrices import banded_random
-from repro.runtime import DevicePool, HeterogeneousEngine
+#: host devices the CPU run forces in its child process
+HOST_DEVICES = 8
 
-r, c, v, n = banded_random(120_000, bw=16, density=0.6, seed=0)
-mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
-eng = HeterogeneousEngine(r, c, v, n, mesh=mesh,
-                          pool=DevicePool.from_bandwidths([1.0] * 8),
-                          C=32, sigma=256, w_align=4, dtype=np.float32)
-D = eng.A
-rng = np.random.default_rng(0)
-x = rng.standard_normal((n, 1)).astype(np.float32)
-xs = D.distribute_vec(x)
 
-for name, ov, db in (("no_overlap", False, False),
-                     ("overlap", True, False),
-                     ("overlap_dbuf", True, True)):
-    run = eng.make_matvec(overlap=ov, nvecs=1, double_buffer=db)
-    stg = eng.init_staging(1, np.float32) if db else None
-    y, _, _ = run(xs, staging=stg); jax.block_until_ready(y)
-    ts = []
-    for _ in range(20):
-        t0 = time.perf_counter()
-        y, _, s = run(xs, staging=stg)
-        if db:
-            stg = s
-        jax.block_until_ready(y); ts.append(time.perf_counter() - t0)
-    t = float(np.median(ts))
-    print(f"RES,{name},{t*1e6:.1f}")
+def measure() -> dict:
+    """Median SpMV time of each schedule over every device of this
+    process, plus the halo statistics."""
+    import jax
+    from jax.sharding import Mesh
+    from repro.matrices import banded_random
+    from repro.runtime import DevicePool, HeterogeneousEngine
 
-lm = int(np.asarray(D.l_vals != 0).sum()); rm = int(np.asarray(D.r_vals != 0).sum())
-print(f"RES,halo,{0:.1f},max_msg={D.max_msg};h_max={D.h_max};"
-      f"local_nnz={lm};remote_nnz={rm};remote_frac={rm/(lm+rm):.4f}")
-"""
+    devices = jax.devices()
+    r, c, v, n = banded_random(120_000, bw=16, density=0.6, seed=0)
+    mesh = Mesh(np.array(devices), ("data",))
+    eng = HeterogeneousEngine(
+        r, c, v, n, mesh=mesh,
+        pool=DevicePool.from_bandwidths([1.0] * len(devices)),
+        C=32, sigma=256, w_align=4, dtype=np.float32)
+    D = eng.A
+    x = np.random.default_rng(0).standard_normal((n, 1)).astype(np.float32)
+    xs = D.distribute_vec(x)
+
+    res = {}
+    for name, ov, db in (("no_overlap", False, False),
+                         ("overlap", True, False),
+                         ("overlap_dbuf", True, True)):
+        run = eng.make_matvec(overlap=ov, nvecs=1, double_buffer=db)
+        stg = eng.init_staging(1, np.float32) if db else None
+        y, _, _ = run(xs, staging=stg)
+        jax.block_until_ready(y)
+        ts = []
+        for _ in range(20):
+            t0 = time.perf_counter()
+            y, _, s = run(xs, staging=stg)
+            if db:
+                stg = s
+            jax.block_until_ready(y)
+            ts.append(time.perf_counter() - t0)
+        res[name] = float(np.median(ts)) * 1e6
+
+    lm = int(np.asarray(D.l_vals != 0).sum())
+    rm = int(np.asarray(D.r_vals != 0).sum())
+    res["halo"] = (f"max_msg={D.max_msg};h_max={D.h_max};local_nnz={lm};"
+                   f"remote_nnz={rm};remote_frac={rm / (lm + rm):.4f}")
+    return res
+
+
+def _measure_in_child() -> dict:
+    """Run :func:`measure` on forced host devices in a fresh interpreter
+    (the device count is fixed when JAX first initializes)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
+                        f"{HOST_DEVICES}")
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), root])
+    out = subprocess.run(
+        [sys.executable, "-m", "benchmarks.fig5_overlap", "--child"],
+        env=env, cwd=root, capture_output=True, text=True, timeout=560)
+    if out.returncode != 0:
+        raise RuntimeError(f"fig5_overlap child exited {out.returncode}: "
+                           f"{out.stderr[-2000:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def main():
+    import jax
+
     policy_row("fig5_overlap")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run([sys.executable, "-c", CODE], env=env,
-                         capture_output=True, text=True, timeout=560)
-    if out.returncode != 0:
-        row("fig5_overlap", 0.0, f"FAILED:{out.stderr[-200:]}")
-        return
-    res = {}
-    for line in out.stdout.splitlines():
-        if line.startswith("RES,"):
-            parts = line.split(",", 3)
-            res[parts[1]] = parts[2:]
-    t_no = float(res["no_overlap"][0])
-    t_ov = float(res["overlap"][0])
-    t_db = float(res["overlap_dbuf"][0])
+    if jax.default_backend() == "tpu":
+        # a chip belongs to one process: the one already holding it
+        res = measure()
+    else:
+        res = _measure_in_child()
+    t_no, t_ov, t_db = res["no_overlap"], res["overlap"], res["overlap_dbuf"]
     row("fig5_spmv_no_overlap", t_no, "mode=barrier")
     row("fig5_spmv_overlap", t_ov,
         f"mode=task;speedup={t_no / max(t_ov, 1e-9):.2f}x")
@@ -80,8 +104,11 @@ def main():
     # is the buffer-rotation copy, reported as overhead vs plain task mode
     row("fig5_spmv_overlap_dbuf", t_db,
         f"mode=task+staging;staging_overhead={t_db / max(t_ov, 1e-9):.2f}x")
-    row("fig5_halo", 0.0, res["halo"][1])
+    row("fig5_halo", 0.0, res["halo"])
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["--child"]:
+        print(json.dumps(measure()))
+    else:
+        main()

@@ -91,8 +91,10 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
-    print("name,us_per_call,derived")
     from repro.core import execution
+    execution.use_compile_cache(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    print("name,us_per_call,derived")
     print(f"execution_policy,0.0,{execution.describe()}")
     benches: dict = {}
     failed = []

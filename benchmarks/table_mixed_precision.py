@@ -22,6 +22,7 @@ price of the narrower values (typically 0-15% on a Laplacian at 1e-6).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import policy_row, row, time_fn
@@ -83,8 +84,7 @@ def main():
 
     times, iters = {}, {}
     try:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             times["f64"], iters["f64"] = _run_variant(
                 "f64", r, c, v, n, dtype=np.float64, store_dtype=None,
                 impl=impl)

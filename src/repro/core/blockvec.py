@@ -22,6 +22,10 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+# full f32 precision in every contraction: a TPU otherwise runs an f32
+# matmul as one bfloat16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "tsmttsm", "tsmm", "tsmm_inplace", "axpy", "axpby", "scal", "dot",
     "vaxpy", "vaxpby", "vscal", "tsmttsm_kahan", "dot_kahan",
@@ -79,7 +83,7 @@ def tsmttsm(V: jax.Array, W: jax.Array, X: Optional[jax.Array] = None,
     """
     check_beta_needs_out(beta, X, "tsmttsm")
     Vh = jnp.conj(V) if (conj and jnp.iscomplexobj(V)) else V
-    prod = jnp.einsum("nm,nk->mk", Vh, W,
+    prod = jnp.einsum("nm,nk->mk", Vh, W, precision=_HIGHEST,
                       preferred_element_type=_acc_dtype(V.dtype, W.dtype))
     out = alpha * prod
     if X is not None:
@@ -91,7 +95,7 @@ def tsmm(V: jax.Array, X: jax.Array, W: Optional[jax.Array] = None,
          alpha=1.0, beta=0.0) -> jax.Array:
     """W = alpha * V X + beta * W.   V: (n, m), X: (m, k) -> (n, k)."""
     check_beta_needs_out(beta, W, "tsmm")
-    prod = jnp.einsum("nm,mk->nk", V, X,
+    prod = jnp.einsum("nm,mk->nk", V, X, precision=_HIGHEST,
                       preferred_element_type=_acc_dtype(V.dtype, X.dtype))
     out = alpha * prod
     if W is not None:
@@ -189,6 +193,6 @@ def tsmttsm_kahan(V: jax.Array, W: jax.Array, *, block: int = 256) -> jax.Array:
         W = jnp.pad(W, ((0, pad), (0, 0)))
     Vb = Vh.reshape(nb, block, m)
     Wb = W.reshape(nb, block, k)
-    partials = jnp.einsum("zbm,zbk->zmk", Vb, Wb,
+    partials = jnp.einsum("zbm,zbk->zmk", Vb, Wb, precision=_HIGHEST,
                           preferred_element_type=_acc_dtype(V.dtype, W.dtype))
     return _kahan_reduce(partials)

@@ -32,33 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import execution
 from repro.core import partition as part
-from repro.core.sellcs import SellCS, from_coo
-from repro.core.spmv import SpmvOpts, spmv_ref
-
-# Newer jax exposes shard_map at top level; older releases keep it in
-# jax.experimental.  The replication-check kwarg was also renamed along
-# the way (check_rep= -> check_vma=), and both renames happened in
-# different releases, so feature-detect each independently.  Resolved
-# once here so every SPMD caller in the repo shares the shim.  The check
-# is disabled because pallas_call runs inside our shard_maps.
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-try:
-    import inspect
-    _sm_params = inspect.signature(_shard_map_impl).parameters
-    _SM_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                         if k in _sm_params), None)
-except (TypeError, ValueError):  # signature not introspectable
-    _SM_CHECK_KW = "check_vma"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    kw = {_SM_CHECK_KW: False} if _SM_CHECK_KW else {}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+from repro.core.sellcs import SellCS, _uniform_width, from_coo
+from repro.core.spmv import SpmvOpts, row_sums, spmv_ref
 
 __all__ = [
     "DistSellCS", "dist_from_coo", "dist_spmv", "make_dist_spmv",
@@ -118,6 +93,11 @@ class DistSellCS:
     # narrower; None = values are stored in the compute dtype
     compute_dtype: Optional[str] = dataclasses.field(
         default=None, metadata=dict(static=True))
+    # chunk width shared by every shard's local / remote part when the
+    # stacked slots form a dense (ncks, width, C) block per shard; 0 =
+    # ragged (see SellCS.uniform_width)
+    l_width: int = dataclasses.field(default=0, metadata=dict(static=True))
+    r_width: int = dataclasses.field(default=0, metadata=dict(static=True))
 
     # ------------------------------------------------------------------
     @property
@@ -163,6 +143,8 @@ def dist_from_coo(
     dtype=None,
     store_dtype=None,
     ranges: Optional[Sequence[Tuple[int, int]]] = None,
+    mesh: Optional[Mesh] = None,
+    axis: str = "data",
 ) -> DistSellCS:
     """Build a row-distributed SELL-C-sigma matrix from global COO (square).
 
@@ -174,6 +156,10 @@ def dist_from_coo(
     a narrower storage dtype end-to-end (the halo exchange itself moves
     vector data in the compute ``dtype``; only matrix values narrow) —
     see :func:`repro.core.sellcs.from_coo`.
+
+    With ``mesh``, each shard's slice of the stacked per-shard arrays is
+    placed on its own device of mesh axis ``axis``; without it they land
+    on the default device.
     """
     rows = np.asarray(rows, np.int64)
     cols = np.asarray(cols, np.int64)
@@ -309,21 +295,37 @@ def dist_from_coo(
     l_off, l_len = stack_chunks(locals_, capL)
     r_off, r_len = stack_chunks(remotes, capR)
 
+    def stacked_width(mats, cap):
+        # a shard with fewer chunks maps its padding chunks onto the
+        # zero padding of the stack, so only the widths must agree
+        w = _uniform_width(np.concatenate(
+            [np.asarray(M.chunk_len) for M in mats]))
+        return w if cap == ncks * w * C else 0
+
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        by_shard = NamedSharding(mesh, P(axis))
+        put = partial(jax.device_put, device=by_shard)
+
+    def idx(a):
+        return put(np.asarray(a, np.int32))
+
     vdt = locals_[0].vals.dtype
     return DistSellCS(
-        l_vals=jnp.asarray(stack([M.vals for M in locals_], capL, dt=vdt)),
-        l_cols=jnp.asarray(stack([M.cols for M in locals_], capL, dt=np.int64), jnp.int32),
-        l_off=jnp.asarray(l_off, jnp.int32),
-        l_len=jnp.asarray(l_len, jnp.int32),
-        l_rowids=jnp.asarray(stack([M.rowids for M in locals_], capL, dt=np.int64), jnp.int32),
-        r_vals=jnp.asarray(stack([M.vals for M in remotes], capR, dt=vdt)),
-        r_cols=jnp.asarray(stack([M.cols for M in remotes], capR, dt=np.int64), jnp.int32),
-        r_off=jnp.asarray(r_off, jnp.int32),
-        r_len=jnp.asarray(r_len, jnp.int32),
-        r_rowids=jnp.asarray(stack([M.rowids for M in remotes], capR, dt=np.int64), jnp.int32),
-        send_idx=jnp.asarray(send_idx, jnp.int32),
-        halo_idx=jnp.asarray(halo_idx, jnp.int32),
-        g2l=jnp.asarray(g2l, jnp.int32),
+        l_vals=put(stack([M.vals for M in locals_], capL, dt=vdt)),
+        l_cols=idx(stack([M.cols for M in locals_], capL, dt=np.int64)),
+        l_off=idx(l_off),
+        l_len=idx(l_len),
+        l_rowids=idx(stack([M.rowids for M in locals_], capL, dt=np.int64)),
+        r_vals=put(stack([M.vals for M in remotes], capR, dt=vdt)),
+        r_cols=idx(stack([M.cols for M in remotes], capR, dt=np.int64)),
+        r_off=idx(r_off),
+        r_len=idx(r_len),
+        r_rowids=idx(stack([M.rowids for M in remotes], capR, dt=np.int64)),
+        send_idx=idx(send_idx),
+        halo_idx=idx(halo_idx),
+        g2l=idx(g2l),
         pos_of_global=jnp.asarray(pos_of_global, jnp.int32),
         row_ranges=tuple((int(s), int(e)) for (s, e) in ranges),
         shard_nnz=tuple(int(L.nnz + R.nnz)
@@ -337,6 +339,8 @@ def dist_from_coo(
         max_msg=max_msg,
         h_max=h_max,
         compute_dtype=locals_[0].compute_dtype,
+        l_width=stacked_width(locals_, capL),
+        r_width=stacked_width(remotes, capR),
     )
 
 
@@ -350,9 +354,9 @@ def dist_from_coo(
 # double-buffered halo staging for the heterogeneous engine.
 # ---------------------------------------------------------------------------
 
-def _shard_spmv_ref(vals, cols, rowids, x, m_pad, acc_dt):
+def _shard_spmv_ref(vals, cols, rowids, x, m_pad, acc_dt, C, width):
     contrib = vals[:, None].astype(acc_dt) * x[cols].astype(acc_dt)
-    return jax.ops.segment_sum(contrib, rowids, num_segments=m_pad)
+    return row_sums(contrib, rowids, m_pad, C, width)
 
 
 def _shard_spmv_pallas(vals, cols, off, ln, x, C, w_tile, interpret,
@@ -393,7 +397,8 @@ def local_stage(A: DistSellCS, shard: dict, x_local: jax.Array,
                                   A.C, A.w_align, interpret,
                                   compute_dtype=acc_dt).astype(acc_dt)
     return _shard_spmv_ref(shard["l_vals"], shard["l_cols"],
-                           shard["l_rowids"], x_local, A.m_pad, acc_dt)
+                           shard["l_rowids"], x_local, A.m_pad, acc_dt,
+                           A.C, A.l_width)
 
 
 def remote_stage(A: DistSellCS, shard: dict, halo: jax.Array,
@@ -405,7 +410,8 @@ def remote_stage(A: DistSellCS, shard: dict, halo: jax.Array,
                                   A.C, A.w_align, interpret,
                                   compute_dtype=acc_dt).astype(acc_dt)
     return _shard_spmv_ref(shard["r_vals"], shard["r_cols"],
-                           shard["r_rowids"], halo, A.m_pad, acc_dt)
+                           shard["r_rowids"], halo, A.m_pad, acc_dt,
+                           A.C, A.r_width)
 
 
 def fused_epilogue(Ax: jax.Array, x_local: jax.Array, axis: str,
@@ -561,10 +567,12 @@ def make_dist_spmv(
         return y[None], (jnp.zeros((1, 3, nvecs), y.dtype) if dots is None
                          else dots[None].astype(y.dtype))
 
-    mapped = shard_map(
+    # check_vma is off because pallas_call runs inside the shard_map
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, P(axis, None, None)),
         out_specs=(P(axis, None, None), P(axis, None, None)),
+        check_vma=False,
     )
 
     @jax.jit

@@ -23,7 +23,9 @@ Pallas kernel in the repo:
   if the *compiled* path fails (e.g. mode forced on a backend without
   Pallas support), falls back to the jnp reference with a one-time
   warning instead of crashing.  Interpret-mode failures still raise:
-  those are logic bugs, not capability gaps.
+  those are logic bugs, not capability gaps.  On a TPU backend the
+  fallback is off: a compiled failure there raises, so no measurement
+  on the chip can silently time the reference.
 
 Resolution happens at trace time.  A function jitted under one policy
 keeps its compiled mode until retraced; enter :func:`force` *before*
@@ -53,6 +55,7 @@ __all__ = [
     "resolve_interpret", "resolve_w_tile", "resolve_row_tile",
     "resolve_s_blk", "cascade", "compiled_available",
     "degrade_to_reference", "autotune", "describe", "reset",
+    "use_compile_cache",
 ]
 
 T = TypeVar("T")
@@ -87,6 +90,7 @@ class ExecutionPolicy:
     row_tile: int = 512
     s_blk: int = 64
     fallback: bool = True                 # cascade to jnp ref on failure
+                                          # (default off on a compiled backend)
 
     @property
     def mode(self) -> str:
@@ -148,7 +152,8 @@ def default_policy() -> ExecutionPolicy:
             w_tile=_env_int(ENV_W_TILE),
             row_tile=_env_int(ENV_ROW_TILE) or 512,
             s_blk=_env_int(ENV_S_BLK) or 64,
-            fallback=_env_bool(ENV_FALLBACK) is not False,
+            fallback=(backend not in COMPILED_BACKENDS
+                      and _env_bool(ENV_FALLBACK) is not False),
         )
     return _default
 
@@ -218,7 +223,9 @@ def compiled_available() -> bool:
     cached; :func:`reset` clears it).  The probe makes the cascade a
     Python-level branch at *trace* time, so a forced-compiled policy on a
     Pallas-less backend falls back cleanly even inside ``lax.while_loop``
-    solver bodies, where a lowering error could not be caught.
+    solver bodies, where a lowering error could not be caught.  On a
+    backend that should compile Pallas (``COMPILED_BACKENDS``) a failed
+    probe raises instead of answering False.
     """
     global _compiled_ok
     if _compiled_ok is None:
@@ -245,9 +252,15 @@ def compiled_available() -> bool:
                 jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
             _compiled_ok = True
         # any lowering/compile failure means "compiled unavailable" —
-        # the probe's whole job is to swallow it
+        # the probe's whole job is to swallow it, except where the
+        # backend should compile Pallas
         # ghostlint: disable=GL008
-        except Exception:                                   # noqa: BLE001
+        except Exception as e:                              # noqa: BLE001
+            backend = default_policy().backend
+            if backend in COMPILED_BACKENDS:
+                raise RuntimeError(
+                    f"compiled Pallas probe failed on backend {backend!r}: "
+                    f"{type(e).__name__}: {e}") from e
             _compiled_ok = False
     return _compiled_ok
 
@@ -291,9 +304,9 @@ def cascade(kernel: str,
     residual failure while the specialized call runs — falls back to
     ``reference()`` with a one-time ``RuntimeWarning`` per kernel name.
     Interpret-mode failures always propagate (they are correctness bugs).
-    Set ``REPRO_FALLBACK=0`` (or ``force(fallback=False)``) to make
-    compiled failures fatal, e.g. in a TPU CI job that must never
-    silently degrade.
+    Compiled failures are fatal on a TPU backend (the policy's fallback
+    default is off there), and anywhere under ``REPRO_FALLBACK=0`` or
+    ``force(fallback=False)``.
     """
     pol = current_policy()
     it = pol.interpret if interpret is None else bool(interpret)
@@ -370,6 +383,21 @@ def describe(pol: Optional[ExecutionPolicy] = None) -> str:
         knobs += f";w_tile={p.w_tile}"
     return (f"mode={p.mode};backend={p.backend};source={p.source};"
             f"fallback={p.fallback};{knobs}")
+
+
+def use_compile_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compile cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is honoured as JAX reads
+    it; otherwise the cache sits at the fixed ``<checkout>/.jax_cache``
+    (a fixed path, because the path is part of what a later run must
+    find again).  Returns the directory in use.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def reset() -> None:

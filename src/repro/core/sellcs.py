@@ -81,6 +81,10 @@ class SellCS:
     # None = vals *are* the compute dtype (the classic single-dtype layout)
     compute_dtype: Optional[str] = dataclasses.field(
         default=None, metadata=dict(static=True))
+    # the padded width shared by every chunk, when all are equal (the
+    # slots then form a dense (nchunks, width, C) block); 0 = ragged
+    uniform_width: int = dataclasses.field(
+        default=0, metadata=dict(static=True))
 
     # ------------------------------------------------------------------ api
     @property
@@ -338,7 +342,16 @@ def from_coo(
         w_align=int(w_align),
         permuted_cols=bool(permuted_cols),
         compute_dtype=compute_dtype,
+        uniform_width=_uniform_width(chunk_len),
     )
+
+
+def _uniform_width(chunk_len) -> int:
+    """The width every chunk shares, or 0 if the widths differ."""
+    chunk_len = np.asarray(chunk_len)
+    if chunk_len.size and (chunk_len == chunk_len[0]).all():
+        return int(chunk_len[0])
+    return 0
 
 
 def from_csr(indptr, indices, data, shape, **kw) -> SellCS:

@@ -125,11 +125,14 @@ def dot_acc_dtype(dt):
 def compensated_sum0(p: jax.Array, block: int = 256) -> jax.Array:
     """Kahan-compensated sum over axis 0 (the "or Kahan acc" leg).
 
-    Blocks of ``block`` rows are reduced natively, then the block
+    Blocks of ``block`` rows are summed pairwise, then the block
     partials are Kahan-accumulated (``blockvec._kahan_reduce``, the same
     compensation the paper's tsmttsm uses), shrinking the uncompensated
-    window from ``n`` to ``block`` summands.  Used for the fused dots
-    when float64 is unavailable.
+    window from ``n`` to ``block`` summands.  The pairwise order is
+    spelled out because XLA's own reduce order is unspecified (XLA's CPU
+    backend sums sequentially, which loses up to ``block - 1`` half-ulps
+    against a spike).  Used for the fused dots when float64 is
+    unavailable.
     """
     n = p.shape[0]
     if n == 0:
@@ -137,8 +140,13 @@ def compensated_sum0(p: jax.Array, block: int = 256) -> jax.Array:
     pad = (-n) % block
     if pad:
         p = jnp.pad(p, ((0, pad),) + ((0, 0),) * (p.ndim - 1))
-    parts = p.reshape(-1, block, *p.shape[1:]).sum(axis=1)
-    return blockvec._kahan_reduce(parts)
+    parts = p.reshape(-1, block, *p.shape[1:])
+    while parts.shape[1] > 1:
+        if parts.shape[1] % 2:
+            parts = jnp.pad(parts, ((0, 0), (0, 1)) +
+                            ((0, 0),) * (parts.ndim - 2))
+        parts = parts[:, 0::2] + parts[:, 1::2]
+    return blockvec._kahan_reduce(parts[:, 0])
 
 
 def _acc_dot(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -192,7 +200,7 @@ def spmv_ref(
     # a narrower store_dtype upcasts per-element before the products
     acc_dt = jnp.result_type(A.dtype, x2.dtype)
     contrib = A.vals.astype(acc_dt)[:, None] * x2.astype(acc_dt)[A.cols]
-    Ax = jax.ops.segment_sum(contrib, A.rowids, num_segments=n)
+    Ax = row_sums(contrib, A.rowids, n, A.C, A.uniform_width)
 
     if opts.gamma is not None:
         gamma = jnp.asarray(opts.gamma)
@@ -222,6 +230,23 @@ def spmv_ref(
     if was1d:
         ynew = ynew[:, 0]
     return ynew, znew, dots
+
+
+def row_sums(contrib: jax.Array, rowids: jax.Array, nrows_pad: int,
+             C: int, width: int) -> jax.Array:
+    """Per-row sums of the per-slot products ``contrib`` (cap, b) of a
+    SELL-C-sigma matrix.
+
+    With a uniform chunk ``width`` the slots form a dense
+    ``(nchunks, width, C)`` block and the row sums are a reduction over
+    its width axis.  Otherwise they are a segment sum over ``rowids``:
+    a scatter-add, which a TPU runs element by element.
+    """
+    if width:
+        b = contrib.shape[1]
+        return contrib.reshape(nrows_pad // C, width, C, b).sum(
+            axis=1).reshape(nrows_pad, b)
+    return jax.ops.segment_sum(contrib, rowids, num_segments=nrows_pad)
 
 
 def spmv(

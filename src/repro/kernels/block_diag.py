@@ -39,11 +39,12 @@ def _kernel(blocks_ref, x_ref, o_ref, *, nbt: int, bs: int, b: int,
     acc_dt = _acc_dtype(out_dtype)
     bl = blocks_ref[...].astype(acc_dt)                  # (nbt, bs, bs)
     xb = x_ref[...].astype(acc_dt).reshape(nbt, bs, b)   # (nbt, bs, b)
-    # batched small matmul: y[k] = B_k @ x[k]
+    # batched small matmul: y[k] = B_k @ x[k] (HIGHEST: a TPU otherwise
+    # runs an f32 matmul as one bfloat16 pass)
     y = jax.lax.dot_general(
         bl, xb,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=acc_dt)
+        preferred_element_type=acc_dt, precision=jax.lax.Precision.HIGHEST)
     o_ref[...] = y.reshape(nbt * bs, b).astype(out_dtype)
 
 

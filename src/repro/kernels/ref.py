@@ -26,7 +26,8 @@ def block_diag_matmul_ref(blocks: jax.Array, x: jax.Array) -> jax.Array:
     """
     nb, bs, _ = blocks.shape
     xb = x.reshape(nb, bs, x.shape[1])
-    y = jnp.einsum("kij,kjb->kib", blocks, xb)
+    y = jnp.einsum("kij,kjb->kib", blocks, xb,
+                   precision=jax.lax.Precision.HIGHEST)
     return y.reshape(nb * bs, x.shape[1])
 
 

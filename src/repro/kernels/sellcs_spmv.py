@@ -32,7 +32,10 @@ storage width and is upcast in-register before the ``einsum``, halving the
 dominant memory traffic of this bandwidth-bound kernel
 (``docs/mixed_precision.md``).
 
-Validated in ``interpret=True`` mode against ``core.spmv.spmv_ref``.
+Validated in ``interpret=True`` mode against ``core.spmv.spmv_ref``.  It
+has no compiled form yet: Mosaic refuses the ``x_ref[cslab]`` gather from
+the ``pl.ANY``-placed ``x`` (see :data:`NO_LOWERING`), so a compiled call
+raises and the SpMV runs as ``impl="ref"`` on the chip.
 """
 from __future__ import annotations
 
@@ -50,7 +53,15 @@ from repro.core import execution
 from repro.core.spmv import (compensated_sum0, dot_acc_dtype,
                              storage_acc_dtype as _acc_dtype)
 
-__all__ = ["sellcs_spmv_pallas"]
+__all__ = ["sellcs_spmv_pallas", "NO_LOWERING"]
+
+#: why a compiled call is refused: what Mosaic (JAX 0.9.0) reports when it
+#: lowers this kernel for a TPU v5e
+NO_LOWERING = (
+    "sellcs_spmv_pallas has no Mosaic lowering: the x[cols] gather reads "
+    "a pl.ANY ref, and Mosaic refuses it with 'Loads are only allowed on "
+    "VMEM and SMEM references. ANY memory space can only be accessed "
+    "using async_copy.'  Run the SpMV with impl='ref'.")
 
 
 def _kernel(
@@ -86,8 +97,8 @@ def _kernel(
 
     def body(j, acc):
         base = (off + j * w_tile) * C
-        cslab = pl.load(cols_ref, (pl.ds(base, w_tile * C),))
-        vslab = pl.load(vals_ref, (pl.ds(base, w_tile * C),)).astype(acc_dt)
+        cslab = cols_ref[pl.ds(base, w_tile * C)]
+        vslab = vals_ref[pl.ds(base, w_tile * C)].astype(acc_dt)
         xg = x_ref[cslab]                              # (w_tile*C, b) gather
         xg = xg.reshape(w_tile, C, b).astype(acc_dt)
         vslab = vslab.reshape(w_tile, C)
@@ -102,7 +113,7 @@ def _kernel(
 
     need_xrow = has_gamma or dot_xy or dot_xx
     if need_xrow:
-        xrow = pl.load(x_ref, (pl.ds(c * C, C), slice(None))).astype(acc_dt)
+        xrow = x_ref[pl.ds(c * C, C), :].astype(acc_dt)
     if has_gamma:
         g = gamma_ref[...].astype(acc_dt)              # (1, b) or (1, 1)
         acc = acc - g * xrow
@@ -166,6 +177,8 @@ def sellcs_spmv_pallas(
     width while the accumulator is at least f32.
     """
     interpret = execution.resolve_interpret(interpret)
+    if not interpret:
+        raise NotImplementedError(NO_LOWERING)
     if w_tile <= 0:
         raise ValueError(f"w_tile must be positive, got {w_tile}")
     if not isinstance(chunk_len, jax.core.Tracer):

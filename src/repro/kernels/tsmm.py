@@ -29,8 +29,10 @@ def _kernel(v_ref, x_ref, coef_ref, win_ref, out_ref, *,
     acc_dt = _acc_dtype(out_dtype)
     v = v_ref[...].astype(acc_dt)
     x = x_ref[...].astype(acc_dt)
+    # HIGHEST: a TPU otherwise runs an f32 matmul as one bfloat16 pass
     prod = jax.lax.dot_general(
-        v, x, (((1,), (0,)), ((), ())), preferred_element_type=acc_dt)
+        v, x, (((1,), (0,)), ((), ())), preferred_element_type=acc_dt,
+        precision=jax.lax.Precision.HIGHEST)
     alpha = coef_ref[0, 0]
     res = alpha * prod
     if has_win:

@@ -27,6 +27,10 @@ from repro.core.spmv import storage_acc_dtype as _acc_dtype
 
 __all__ = ["tsmttsm_pallas"]
 
+# every dot_general here asks for full f32 precision: a TPU otherwise
+# runs an f32 matmul as one bfloat16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _kernel(v_ref, w_ref, coef_ref, xin_ref, out_ref, acc_ref, comp_ref,
             *, kahan: bool, conj: bool, has_xin: bool, out_dtype):
@@ -40,30 +44,32 @@ def _kernel(v_ref, w_ref, coef_ref, xin_ref, out_ref, acc_ref, comp_ref,
             comp_ref[...] = jnp.zeros_like(comp_ref)
 
     acc_dt = acc_ref.dtype
-    v = v_ref[...].astype(acc_dt)
-    if conj:
-        v = jnp.conj(v)
-    w = w_ref[...].astype(acc_dt)
+
+    def slabs(rows):
+        v = v_ref[rows, :].astype(acc_dt)
+        if conj:
+            v = jnp.conj(v)
+        return v, w_ref[rows, :].astype(acc_dt)
 
     if kahan:
         # Compensation can only absorb error *between* summands, so a
         # single (row_tile)-deep dot would leave its internal rounding
         # uncompensated.  Walk the slab in 8-row micro-slabs (8 = VPU
         # sublane height; smaller divisor for odd tiles) and Kahan-
-        # accumulate one 2-D dot per micro-slab — plain 2-D dots and
-        # aligned dynamic_slice so Mosaic lowers it (batched rank-3
-        # dot_general would not).  The uncompensated window shrinks
-        # from row_tile to g rows.
-        g = next(d for d in (8, 4, 2, 1) if v.shape[0] % d == 0)
-        G = v.shape[0] // g
+        # accumulate one 2-D dot per micro-slab.  The micro-slabs are
+        # read straight from the refs at aligned offsets: Mosaic has no
+        # lowering for dynamic_slice on loaded values, nor for a batched
+        # rank-3 dot_general.  The uncompensated window shrinks from
+        # row_tile to g rows.
+        g = next(d for d in (8, 4, 2, 1) if v_ref.shape[0] % d == 0)
+        G = v_ref.shape[0] // g
 
         def body(j, carry):
             acc, comp = carry
-            vs = jax.lax.dynamic_slice_in_dim(v, j * g, g, 0)
-            ws = jax.lax.dynamic_slice_in_dim(w, j * g, g, 0)
+            vs, ws = slabs(pl.ds(pl.multiple_of(j * g, g), g))
             part = jax.lax.dot_general(
                 vs, ws, (((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dt)
+                preferred_element_type=acc_dt, precision=_HIGHEST)
             y = part - comp
             t = acc + y
             return t, (t - acc) - y
@@ -73,8 +79,10 @@ def _kernel(v_ref, w_ref, coef_ref, xin_ref, out_ref, acc_ref, comp_ref,
         acc_ref[...] = acc
         comp_ref[...] = comp
     else:
+        v, w = slabs(slice(None))
         term = jax.lax.dot_general(
-            v, w, (((0,), (0,)), ((), ())), preferred_element_type=acc_dt)
+            v, w, (((0,), (0,)), ((), ())), preferred_element_type=acc_dt,
+            precision=_HIGHEST)
         acc_ref[...] = acc_ref[...] + term
 
     @pl.when(i == nsteps - 1)

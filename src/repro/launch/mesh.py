@@ -17,11 +17,15 @@ import jax
 __all__ = ["make_production_mesh", "make_host_mesh", "HW"]
 
 
-# TPU v5e hardware constants (per chip) for the roofline analysis
+# TPU v5e (device_kind "TPU v5 lite") per-chip peaks, from the Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, and 1,600 Gbit/s of inter-chip interconnect over 4 links.
+# The one table of device peaks: runtime.devicepool keys its v5e entry
+# on it.
 HW = {
     "peak_flops_bf16": 197e12,   # FLOP/s
     "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
+    "ici_bw": 50e9,              # B/s per link (1,600 Gbit/s / 4)
     "hbm_bytes": 16e9,
 }
 

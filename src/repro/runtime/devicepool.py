@@ -5,9 +5,9 @@ attainable memory bandwidth, because SpMV is bandwidth-bound at its code
 balance (6 bytes/flop for double + 32-bit indices).  ``DevicePool``
 reproduces that policy on a jax platform: it groups ``jax.devices()`` into
 classes by ``device_kind``, attaches per-class bandwidth/peak-flop specs
-(known parts from a table, unknown parts from a conservative default), and
-turns :func:`repro.launch.costmodel.spmv_cost` roofline terms into
-per-device throughput estimates -> split weights.
+from a table of published numbers (a kind missing from it is an error,
+never a guess), and turns :func:`repro.launch.costmodel.spmv_cost`
+roofline terms into per-device throughput estimates -> split weights.
 
 The weights are *estimates to start from*; the engine's rebalance loop
 (:meth:`repro.runtime.split.SplitPlan.rebalance`) refines them online from
@@ -28,30 +28,29 @@ from repro.launch.mesh import HW
 __all__ = ["DeviceClass", "DevicePool", "KNOWN_DEVICE_SPECS"]
 
 
-# Attainable (not peak-datasheet) numbers: mem_bw in B/s, peak_flops in
-# FLOP/s.  The TPU entries come from launch.mesh.HW; the CPU/GPU/PHI
-# entries are the paper's Table 1 reference node (Emmy: SNB socket 50 GB/s,
-# K20 GPU and Xeon Phi ~150 GB/s each) so the paper's experiments are
-# expressible as a synthetic pool.  Matching is by substring of the
-# device_kind, case-insensitive, longest match wins.
+# mem_bw in B/s, peak_flops in FLOP/s, keyed by the exact (lower-cased)
+# ``device_kind`` that JAX reports.  "TPU v5 lite" is the TPU v5e, whose
+# published peaks (and their source) live in launch.mesh.HW.  The CPU/GPU/
+# PHI entries are the paper's Table 1 reference node (Emmy: SNB socket
+# 50 GB/s, K20 GPU and Xeon Phi ~150 GB/s each), so the paper's
+# experiments are expressible as a pool; host CPU devices report the
+# kind "cpu".
 KNOWN_DEVICE_SPECS: Dict[str, Dict[str, float]] = {
-    "tpu v5":  dict(mem_bw=HW["hbm_bw"], peak_flops=HW["peak_flops_bf16"]),
-    "tpu v4":  dict(mem_bw=1.2e12, peak_flops=275e12),
-    "tpu":     dict(mem_bw=HW["hbm_bw"], peak_flops=HW["peak_flops_bf16"]),
+    "tpu v5 lite": dict(mem_bw=HW["hbm_bw"],
+                        peak_flops=HW["peak_flops_bf16"]),
     "gpu":     dict(mem_bw=150e9, peak_flops=1.17e12),   # paper's K20
     "phi":     dict(mem_bw=150e9, peak_flops=1.0e12),    # paper's Xeon Phi
     "cpu":     dict(mem_bw=50e9, peak_flops=0.43e12),    # paper's SNB socket
 }
-_DEFAULT_SPEC = dict(mem_bw=50e9, peak_flops=0.5e12)
 
 
 @dataclasses.dataclass(frozen=True)
 class DeviceClass:
     """One class of identical devices inside a pool."""
 
-    name: str                 # e.g. "TPU v5e", "cpu", "gpu"
+    name: str                 # device_kind, e.g. "TPU v5 lite", "cpu"
     count: int                # devices of this class (contiguous in pool order)
-    mem_bw: float             # attainable HBM bandwidth, B/s
+    mem_bw: float             # memory bandwidth, B/s
     peak_flops: float         # peak compute, FLOP/s
 
     def time_for(self, cost: Cost) -> float:
@@ -65,23 +64,15 @@ class DeviceClass:
         return cost.flops / max(self.time_for(cost), 1e-30)
 
 
-def _lookup_spec(kind: str, platform: str = "") -> Dict[str, float]:
-    """Longest substring match on device_kind, then on platform.
-
-    Real accelerator kind strings rarely contain their platform name
-    (e.g. CUDA reports 'NVIDIA A100-SXM4-40GB'), so the platform
-    fallback is what routes unknown GPUs to the 'gpu' spec instead of
-    the conservative default.
-    """
-    for probe in (kind.lower(), platform.lower()):
-        best = None
-        for key in KNOWN_DEVICE_SPECS:
-            if probe and key in probe and (best is None or
-                                           len(key) > len(best)):
-                best = key
-        if best:
-            return KNOWN_DEVICE_SPECS[best]
-    return dict(_DEFAULT_SPEC)
+def _lookup_spec(kind: str) -> Dict[str, float]:
+    """Specs of one device kind; an unknown kind raises."""
+    try:
+        return KNOWN_DEVICE_SPECS[kind.lower()]
+    except KeyError:
+        raise ValueError(
+            f"no published specs for device kind {kind!r} (known: "
+            f"{sorted(KNOWN_DEVICE_SPECS)}); add the part to "
+            f"KNOWN_DEVICE_SPECS with its source") from None
 
 
 class DevicePool:
@@ -110,7 +101,7 @@ class DevicePool:
                 classes[-1] = dataclasses.replace(
                     classes[-1], count=classes[-1].count + 1)
             else:
-                spec = _lookup_spec(kind, getattr(d, "platform", ""))
+                spec = _lookup_spec(kind)
                 classes.append(DeviceClass(name=kind, count=1, **spec))
         return cls(classes)
 

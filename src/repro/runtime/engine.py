@@ -116,7 +116,7 @@ class HeterogeneousEngine:
             self._rows, self._cols, self._vals, self.nrows,
             nshards=self.plan.nshards, C=self.C, sigma=self.sigma,
             w_align=self.w_align, store_dtype=self.store_dtype,
-            ranges=self.plan.ranges)
+            ranges=self.plan.ranges, mesh=self.mesh, axis=self.axis)
         self._matvec_cache: Dict[tuple, object] = {}
 
     def make_matvec(self, *, overlap: bool = True, impl: str = "ref",

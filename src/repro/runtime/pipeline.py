@@ -45,7 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import execution
 from repro.core.distributed import (
-    DistSellCS, _shard_view, shard_map, spmv_shard_stages,
+    DistSellCS, _shard_view, spmv_shard_stages,
 )
 from repro.core.spmv import SpmvOpts
 
@@ -119,8 +119,10 @@ def make_pipeline_spmv(
             out = out + (staging[None],)
         return out
 
-    mapped = jax.jit(shard_map(
-        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs))
+    # check_vma is off because pallas_call runs inside the shard_map
+    mapped = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
+        check_vma=False))
     any_dot = dot_yy or dot_xy or dot_xx
 
     def run(x_stacked, y_stacked=None, coefs=None, staging=None):

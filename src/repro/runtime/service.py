@@ -1140,7 +1140,8 @@ class SolverService:
             Bop = op.to_op_space(jnp.asarray(Bg))
             X0 = None
             if survivors:
-                xs = batch.state.x[:, [j for j, _, _ in survivors]]
+                keep = np.asarray([j for j, _, _ in survivors])
+                xs = batch.state.x[:, keep]
                 pad = jnp.zeros((xs.shape[0], w - xs.shape[1]), xs.dtype)
                 X0 = jnp.concatenate([xs, pad], axis=1)
             batch.state = batch.init(Bop, jnp.asarray(tols), X0)
@@ -1231,7 +1232,8 @@ class SolverService:
                 retiring.append((j, ticket, spent, "expired"))
         if retiring:
             res = batch.finalize(state)              # one readout per sweep
-            idx = [j for j, _, _, _ in retiring]
+            # an index array, not a list: JAX retraces list indexing
+            idx = np.asarray([j for j, _, _, _ in retiring])
             xs = np.asarray(batch.op.from_op_space(res.x[:, idx]))
             resn = np.asarray(res.resnorm)
             for m, (j, ticket, spent, status) in enumerate(retiring):

@@ -55,6 +55,12 @@ __all__ = ["BlockCGState", "BlockMinresState",
 
 
 # ------------------------------------------------------------- small helpers
+def _mm(a, b):
+    """``a @ b`` at full precision: a TPU otherwise runs an f32 matmul as
+    one bfloat16 pass, which costs block CG its accuracy."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _colsum(v):
     """Per-column squared norm, always real (matches cg._colsum)."""
     if jnp.iscomplexobj(v):
@@ -95,7 +101,7 @@ def _eigh_pinv_apply(G, B, *, rel_eps):
     w, U = jnp.linalg.eigh(_herm(G))
     wmax = jnp.maximum(jnp.max(jnp.abs(w)), jnp.finfo(w.dtype).tiny)
     inv = jnp.where(w > rel_eps * wmax, 1.0 / jnp.where(w == 0, 1.0, w), 0.0)
-    return U @ (inv[:, None] * (jnp.conj(U.T) @ B))
+    return _mm(U, inv[:, None] * _mm(jnp.conj(U.T), B))
 
 
 def _spd_solve(G, B):
@@ -231,12 +237,12 @@ def block_cg_body(op, st: BlockCGState) -> BlockCGState:
     T = op.mv(st.p)                                # one sweep for the block
     G = _herm(_gram(st.p, T))                      # P~ᴴAP~
     gamma = _spd_solve(G, jnp.eye(m, dtype=G.dtype))
-    upd = gamma @ st.cmat                          # γ C — per-column steps
+    upd = _mm(gamma, st.cmat)                      # γ C — per-column steps
     upd = jnp.where(dn[None, :], jnp.zeros((), upd.dtype), upd)
     x = ops.tsmm(st.p, upd, st.x, 1.0, 1.0)        # X += P~ (γ C)
     W = ops.tsmm(T, gamma, st.v, -1.0, 1.0)        # V − (AP~) γ
     Vn, rho = _svqb(W, rel_eps=rel)
-    cn = rho @ st.cmat                             # C_{k+1} = ρ C_k
+    cn = _mm(rho, st.cmat)                         # C_{k+1} = ρ C_k
     rr_new = jnp.where(dn, st.rr, _colsum(cn).astype(st.rr.dtype))
     p = ops.tsmm(st.p, jnp.conj(rho.T), Vn, 1.0, 1.0)  # P~' = V' + P~ ρᴴ
     return BlockCGState(x=x, v=Vn, p=p, cmat=cn, rr=rr_new, tol2=st.tol2,
@@ -328,10 +334,10 @@ def block_minres_body(op, st: BlockMinresState) -> BlockMinresState:
 
     # band column j of T through the two carried reflections
     CprevH = jnp.conj(st.cmat.T)
-    tmp = st.td_old @ CprevH
-    R3 = st.tb_old @ CprevH
-    R2 = st.ta @ tmp + st.tb @ Aj
-    d = st.tc @ tmp + st.td @ Aj
+    tmp = _mm(st.td_old, CprevH)
+    R3 = _mm(st.tb_old, CprevH)
+    R2 = _mm(st.ta, tmp) + _mm(st.tb, Aj)
+    d = _mm(st.tc, tmp) + _mm(st.td, Aj)
     # fresh reflection annihilating C_j under d (block Givens)
     M2 = jnp.concatenate([d, Cj], axis=0)          # (2b, b)
     Qc, Rfull = jnp.linalg.qr(M2, mode="complete")
@@ -339,8 +345,8 @@ def block_minres_body(op, st: BlockMinresState) -> BlockMinresState:
     QH = jnp.conj(Qc.T)
     ta_n, tb_n = QH[:m, :m], QH[:m, m:]
     tc_n, td_n = QH[m:, :m], QH[m:, m:]
-    h_keep = ta_n @ st.h
-    h_next = tc_n @ st.h
+    h_keep = _mm(ta_n, st.h)
+    h_next = _mm(tc_n, st.h)
 
     # W_j = (V_j - W_{j-1} R2 - W_{j-2} R3) R1^{-1}; a rank-deficient R1
     # (exhausted directions) gets unit diagonal stand-ins — their h_keep

@@ -12,6 +12,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# full f32 precision in every contraction: a TPU otherwise runs an f32
+# matmul as one bfloat16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class LanczosResult(NamedTuple):
     alphas: jax.Array      # (k,)   entries past nvalid are zero padding
@@ -63,12 +67,14 @@ def lanczos(op, v0: jax.Array, k: int, *, reorth: bool = False,
         if V is not None:
             V = V.at[:, j].set(jnp.where(alive, v, jnp.zeros_like(v)))
         w = op.mv(v[:, None])[:, 0]
-        alpha = jnp.vdot(v, w)
+        alpha = jnp.vdot(v, w, precision=_HIGHEST)
         w = w - alpha * v - beta * v_prev
         if reorth and V is not None:
             # conjugate transpose: for complex Hermitian operators the
             # projector is V V^H, not V V^T
-            w = w - V @ (V.conj().T @ w)
+            w = w - jnp.matmul(V, jnp.matmul(V.conj().T, w,
+                                             precision=_HIGHEST),
+                               precision=_HIGHEST)
         alphas = alphas.at[j].set(jnp.where(alive, alpha.real, 0.0))
         nvalid = nvalid + alive.astype(jnp.int32)
         beta_new = jnp.linalg.norm(w).astype(rdt)

@@ -129,6 +129,37 @@ class TestCascade:
             with pytest.raises(Exception):
                 jax.block_until_ready(ops.tsmm(V, X))
 
+    def test_tpu_backend_never_falls_back(self, monkeypatch):
+        """On a TPU backend a compiled failure raises: the fallback
+        default is off (REPRO_FALLBACK cannot turn it on there), and a
+        failed compiled-Pallas probe raises instead of degrading."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setenv(execution.ENV_FALLBACK, "1")
+        execution.reset()
+        pol = execution.current_policy()
+        assert pol.backend == "tpu" and not pol.interpret
+        assert pol.fallback is False
+        assert "mode=compiled;backend=tpu" in execution.describe()
+
+        def boom():
+            raise NotImplementedError("no Mosaic lowering")
+        with pytest.raises(NotImplementedError, match="no Mosaic lowering"):
+            execution.cascade("k", boom, lambda: 1)
+        assert execution.degrade_to_reference("k") is False
+        if jax.devices()[0].platform not in execution.COMPILED_BACKENDS:
+            # the probe really compiles on this (Pallas-less) host
+            with pytest.raises(RuntimeError, match="probe failed"):
+                execution.compiled_available()
+
+    def test_compiled_sellcs_spmv_raises_with_reason(self, rng):
+        from repro.kernels.sellcs_spmv import NO_LOWERING
+        m = from_dense(random_sparse(rng, 64, 64), C=8, sigma=1, w_align=4)
+        x = m.permute(rng.standard_normal((64, 1)).astype(np.float32))
+        with pytest.raises(NotImplementedError) as info:
+            sellcs_spmv_pallas(m.vals, m.cols, m.chunk_off, m.chunk_len, x,
+                               C=m.C, w_tile=4, interpret=False)
+        assert str(info.value) == NO_LOWERING
+
     def test_interpret_failures_propagate(self):
         """Interpret-mode bugs are not swallowed by the cascade."""
         def boom():
@@ -170,6 +201,29 @@ class TestCascade:
                     atol=1e-5, rtol=1e-5)
                 y = ops.mamba_scan(dt, dt, B, B, A)
                 assert y.shape == (1, 8, 4)
+
+
+# ------------------------------------------------------------ compile cache
+class TestCompileCache:
+    @pytest.fixture
+    def cache_dir_config(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_dir_is_honoured(self, monkeypatch, tmp_path,
+                                 cache_dir_config):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert execution.use_compile_cache("/some/checkout") == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None  # JAX reads env
+
+    def test_fixed_dir_in_checkout(self, monkeypatch, tmp_path,
+                                   cache_dir_config):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path / ".jax_cache")
+        assert execution.use_compile_cache(str(tmp_path)) == want
+        assert jax.config.jax_compilation_cache_dir == want
 
 
 # ---------------------------------------------------------------- autotune
@@ -223,9 +277,8 @@ class TestDotAccumulation:
         """Fused dots accumulate in f64: one huge chunk partial must not
         swallow the small chunks' mass (exact powers of two throughout,
         so both paths reproduce the true sum bit-for-bit)."""
-        from jax.experimental import enable_x64
         n, C = 256, 32
-        with enable_x64():
+        with jax.enable_x64(True):
             m = from_dense(np.eye(n, dtype=np.float32), C=C, sigma=1)
             x = np.full(n, 8.0, np.float32)
             x[:C] = 0.0
@@ -248,12 +301,11 @@ class TestDotAccumulation:
         """f64 dot accumulation under x64 must not poison the solvers'
         f32 while_loop/scan carries (cg casts the recurrence scalar back,
         kpm casts at the moment boundary)."""
-        from jax.experimental import enable_x64
         from repro.solvers import cg, make_operator
         from repro.solvers.kpm import kpm_dos_moments
         rng = np.random.default_rng(7)
         n = 64
-        with enable_x64():
+        with jax.enable_x64(True):
             a = random_sparse(rng, n, n, density=0.2)
             spd = (a @ a.T + n * np.eye(n)).astype(np.float32)
             m = from_dense(spd, C=8, sigma=16)

@@ -150,7 +150,7 @@ class TestGS102Dtype:
         # the contract's sanctioned boundary, not a violation
         def legal(x):
             return (x * 2.0).astype(jnp.float32)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             fs = audit_function(legal, jnp.ones((4,), jnp.float64),
                                 compute_bits=32)
         assert fs == []
@@ -158,7 +158,7 @@ class TestGS102Dtype:
     def test_x64_roundtrip_through_f32_flagged(self):
         def rt64(x):
             return x.astype(jnp.float32).astype(jnp.float64) * 2.0
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             fs = audit_function(rt64, jnp.ones((4,), jnp.float64),
                                 compute_bits=64)
         assert any("storage round-trip" in f.message for f in fs)

@@ -21,6 +21,26 @@ class TestDevicePool:
         assert pool.ndevices >= 1
         assert len(pool.device_classes()) == pool.ndevices
 
+    def test_v5e_kind_has_published_specs(self):
+        from types import SimpleNamespace
+        from repro.launch.mesh import HW
+        chips = [SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+                 for _ in range(4)]
+        pool = DevicePool.detect(chips)
+        assert [(c.name, c.count) for c in pool.classes] == \
+            [("TPU v5 lite", 4)]
+        assert pool.classes[0].mem_bw == HW["hbm_bw"] == 819e9
+        assert pool.classes[0].peak_flops == HW["peak_flops_bf16"] == 197e12
+        assert np.allclose(pool.device_weights(), 0.25)
+
+    @pytest.mark.parametrize("kind", ["TPU v5", "TPU v5p", "TPU v4",
+                                      "NVIDIA A100-SXM4-40GB"])
+    def test_unknown_accelerator_kind_raises(self, kind):
+        from types import SimpleNamespace
+        dev = SimpleNamespace(device_kind=kind, platform="tpu")
+        with pytest.raises(ValueError, match="no published specs"):
+            DevicePool.detect([dev])
+
     def test_synthetic_paper_node(self):
         """Paper Table 1: CPU 50 + GPU 150 + PHI 150 GB/s."""
         pool = DevicePool.from_bandwidths([50, 150, 150])

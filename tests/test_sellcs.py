@@ -88,6 +88,32 @@ class TestConstruction:
             from_coo([0], [0], [1.0], (3, 3), C=4, sigma=6)  # sigma % C != 0
 
 
+class TestUniformWidth:
+    """Chunks of one common width take the scatter-free row sums."""
+
+    def test_stencil_is_uniform(self):
+        from repro.matrices import laplace3d
+        r, c, v, n = laplace3d(6)
+        assert from_coo(r, c, v, (n, n), C=8, w_align=8).uniform_width == 8
+        assert from_coo(r, c, v, (n, n), C=8, w_align=1).uniform_width == 0
+
+    @pytest.mark.parametrize("b", [1, 3])
+    def test_width_sum_matches_segment_sum(self, rng, b):
+        import dataclasses
+        a = random_sparse(rng, 64, 64, 0.1)
+        m = from_dense(a, C=8, sigma=1, w_align=64)    # every chunk 64 wide
+        assert m.uniform_width == 64
+        ragged = dataclasses.replace(m, uniform_width=0)
+        x = m.permute(rng.standard_normal((64, b)).astype(np.float32))
+        y, _, _ = spmv_ref(m, x)
+        y_seg, _, _ = spmv_ref(ragged, x)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_seg),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(m.unpermute(y)),
+                                   a @ np.asarray(m.unpermute(x)),
+                                   rtol=1e-4, atol=1e-4)
+
+
 class TestStoredZeros:
     """Slot validity comes from construction-recorded row lengths, so
     explicitly stored zeros are structure, not padding."""

@@ -2,6 +2,7 @@
 on the paper's application matrices."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import from_coo
@@ -172,9 +173,8 @@ class TestDtypeFidelity:
     conjugate transpose."""
 
     def test_lanczos_f64(self):
-        from jax.experimental import enable_x64
         from repro.solvers import lanczos
-        with enable_x64():
+        with jax.enable_x64(True):
             r, c, v, n = laplace3d(6)
             A = from_coo(r, c, v, (n, n), C=16, sigma=32, dtype=np.float64)
             op = make_operator(A)
@@ -188,8 +188,7 @@ class TestDtypeFidelity:
             assert lo <= ev[0] + 1e-8 and hi >= ev[-1] - 1e-8
 
     def test_chebfd_f64(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             r, c, v, n = laplace3d(5)
             A = from_coo(r, c, v, (n, n), C=8, sigma=16, dtype=np.float64)
             Ad = np.zeros((n, n)); Ad[r, c] += v
@@ -205,8 +204,7 @@ class TestDtypeFidelity:
                 assert np.abs(ev - f).min() < 5e-3
 
     def test_cg_f64_tiny_floor(self, rng):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             r, c, v, n = laplace3d(5)
             A = from_coo(r, c, v, (n, n), C=8, sigma=16, dtype=np.float64)
             op = make_operator(A)

@@ -3,6 +3,7 @@ monolithic entry points, states merge column-wise, and the matrix-free
 operator's fused dots match the SELL-C-sigma path exactly."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import from_coo
@@ -282,11 +283,10 @@ class TestBlockKrylov:
         fewer) iterations than column CG — the shared space absorbs each
         column's Krylov information."""
         from contextlib import nullcontext
-        from jax.experimental import enable_x64
         A, Ad, n = lap
         scope = nullcontext()
         if dtype == np.float64:
-            scope = enable_x64()
+            scope = jax.enable_x64(True)
             r, c = np.nonzero(Ad)
             A = from_coo(r, c, Ad[r, c].astype(np.float64), (n, n), C=16,
                          sigma=32, w_align=4, dtype=np.float64)

@@ -60,15 +60,14 @@ class TestOptimizers:
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.train.optimizer import compressed_psum
-from repro.core.distributed import shard_map   # version-compat shim
 mesh = Mesh(np.array(jax.devices()).reshape(4), ("pod",))
 x = jnp.arange(4 * 8, dtype=jnp.float32).reshape(4, 8) / 7.0
 
 def f(xs):
     return compressed_psum(xs[0], "pod", bits=8)[None]
 
-y = shard_map(f, mesh=mesh, in_specs=(P("pod", None),),
-              out_specs=P("pod", None))(x)
+y = jax.shard_map(f, mesh=mesh, in_specs=(P("pod", None),),
+                  out_specs=P("pod", None), check_vma=False)(x)
 ref = x.sum(0)
 err = float(jnp.abs(np.asarray(y)[0] - ref).max())
 assert err < 0.2, err

@@ -259,7 +259,7 @@ def run_dtype_audit(verbose: bool = False,
                 t.fn, *t.args, compute_bits=t.compute_bits,
                 target=t.name, anchor_obj=t.anchor_obj))
         # x64 scope: f64 results must not round-trip through f32
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             for t in _iter_x64_targets():
                 if verbose and progress:
                     progress(f"GS102 {t.name}")
