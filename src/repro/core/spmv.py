@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core import blockvec
 from repro.core.sellcs import SellCS
+from repro.core.trace import scope
 
 __all__ = ["SpmvOpts", "as2d", "pack_coefs", "spmv", "spmv_ref",
            "dot_acc_dtype", "storage_acc_dtype", "compensated_sum0",
@@ -168,13 +169,14 @@ def fused_dots(x2: jax.Array, y2: jax.Array, opts: SpmvOpts) -> jax.Array:
     """
     ddt = dot_acc_dtype(jnp.result_type(y2.dtype, x2.dtype))
     b = y2.shape[1]
-    dots = jnp.zeros((3, b), ddt)
-    if opts.dot_yy:
-        dots = dots.at[0].set(_acc_dot(y2, y2))
-    if opts.dot_xy:
-        dots = dots.at[1].set(_acc_dot(x2, y2))
-    if opts.dot_xx:
-        dots = dots.at[2].set(_acc_dot(x2, x2))
+    with scope("dots"):
+        dots = jnp.zeros((3, b), ddt)
+        if opts.dot_yy:
+            dots = dots.at[0].set(_acc_dot(y2, y2))
+        if opts.dot_xy:
+            dots = dots.at[1].set(_acc_dot(x2, y2))
+        if opts.dot_xx:
+            dots = dots.at[2].set(_acc_dot(x2, x2))
     return dots
 
 
@@ -262,11 +264,13 @@ def spmv(
     """Dispatching fused SpMV (GHOST single-interface ``ghost_spmv``).
 
     ``interpret=None`` defers to :mod:`repro.core.execution` (compiled on
-    TPU, interpret elsewhere, env/context overridable).
+    TPU, interpret elsewhere, env/context overridable).  Its device work
+    is named ``ghost.spmv`` (the fused dots inside it ``ghost.dots``).
     """
-    if impl == "ref":
-        return spmv_ref(A, x, y, z, opts)
-    if impl == "pallas":
-        from repro.kernels import ops as kops
-        return kops.sellcs_spmv(A, x, y, z, opts, interpret=interpret)
+    with scope("spmv"):
+        if impl == "ref":
+            return spmv_ref(A, x, y, z, opts)
+        if impl == "pallas":
+            from repro.kernels import ops as kops
+            return kops.sellcs_spmv(A, x, y, z, opts, interpret=interpret)
     raise ValueError(f"unknown impl {impl!r}")

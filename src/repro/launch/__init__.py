@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, roofline, train/serve."""
+"""Launchers: production mesh, multi-pod dry-run, train/serve."""

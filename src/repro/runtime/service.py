@@ -89,12 +89,14 @@ import numpy as np
 
 from repro.core import execution
 from repro.core.sellcs import SellCS, from_coo
+from repro.core.trace import span
 from repro.solvers.cg import (cg_finalize, cg_init, cg_step,
                               pipelined_cg_finalize, pipelined_cg_init,
                               pipelined_cg_step)
 from repro.solvers.minres import minres_finalize, minres_init, minres_step
 from repro.solvers.operator import make_operator
-from repro.solvers.stepper import merge_columns_masked, snap_chunk
+from repro.solvers.stepper import (chunk_programs, merge_columns_masked,
+                                   snap_chunk)
 
 __all__ = ["MatrixRegistry", "SolverService", "SolveTicket", "ServiceResult",
            "SOLVERS", "TERMINAL_STATES"]
@@ -699,7 +701,8 @@ class SolverService:
         self.stats = {"submitted": 0, "retired": 0, "converged": 0,
                       "chunks": 0, "refills": 0, "batches_opened": 0,
                       "cancelled": 0, "expired": 0, "rejected": 0,
-                      "deadline_chunks": 0}
+                      "deadline_chunks": 0, "iters": 0, "column_iters": 0,
+                      "live_column_iters": 0}
 
     # -------------------------------------------------------------- submit
     def submit(self, matrix: str, b, *, solver: str = "cg",
@@ -739,71 +742,72 @@ class SolverService:
         ``docs/block_krylov.md``).  Block and column-wise requests on
         the same matrix batch separately.
         """
-        if solver not in SOLVERS:
-            raise ValueError(f"unknown solver {solver!r} "
-                             f"(have: {sorted(SOLVERS)})")
-        entry = self.registry.entry(matrix)         # validates the handle
-        if block:
-            if solver not in ("cg", "minres"):
-                raise NotImplementedError(
-                    f"block=True supports solver='cg'/'minres', "
-                    f"not {solver!r}")
+        with span("submit"):
+            if solver not in SOLVERS:
+                raise ValueError(f"unknown solver {solver!r} "
+                                 f"(have: {sorted(SOLVERS)})")
+            entry = self.registry.entry(matrix)         # validates the handle
+            if block:
+                if solver not in ("cg", "minres"):
+                    raise NotImplementedError(
+                        f"block=True supports solver='cg'/'minres', "
+                        f"not {solver!r}")
+                if precond is not None:
+                    raise NotImplementedError(
+                        "block=True with a preconditioner is not implemented; "
+                        "drop precond= or submit with block=False")
             if precond is not None:
-                raise NotImplementedError(
-                    "block=True with a preconditioner is not implemented; "
-                    "drop precond= or submit with block=False")
-        if precond is not None:
-            if solver == "pipelined_cg":
-                raise NotImplementedError(
-                    "pipelined_cg does not support preconditioning; "
-                    "use solver='cg' with precond=, or drop precond")
-            from repro.solvers.precond import parse_precond_spec
-            kind, param = parse_precond_spec(precond)   # fail at submit,
-            precond = kind if param is None else f"{kind}:{param}"
-        if deadline is not None and not float(deadline) > 0:
-            raise ValueError(
-                f"deadline must be a positive relative latency target in "
-                f"seconds, got {deadline!r}")
-        if not float(tol) > 0:
-            raise ValueError(f"tol must be > 0, got {tol!r}")
-        # validate the rhs here: a malformed b discovered at refill time
-        # would already have dequeued (and would lose) sibling requests
-        b = np.asarray(b)
-        if b.ndim != 1 or b.shape[0] != entry.nglobal:
-            raise ValueError(
-                f"rhs for {matrix!r} must be 1-d of length {entry.nglobal} "
-                f"(original space), got shape {b.shape}")
-        ticket = SolveTicket(next(self._ids), matrix, solver, b, tol,
-                             maxiter, precond, deadline=deadline,
-                             priority=priority, clock=self.clock)
-        # storage dtype, block mode, and (bucketed admission only) the
-        # difficulty bucket are the trailing key components: requests
-        # against f32-stored and bf16-stored matrices never share a
-        # block solve (their compiled matvecs — and their numerics —
-        # differ), block-Krylov batches never mix with column-wise ones
-        # (their stepper states differ), and predicted-short solves
-        # never share a batch with predicted stragglers
-        bucket = ""
-        if self.admission == "bucketed":
-            pred = self.registry.predicted_iters(
-                matrix, solver=solver, tol=ticket.tol,
-                maxiter=ticket.maxiter)
-            ticket.pred_iters = pred
-            bucket = f"d{int(math.log(pred, self.bucket_base))}"
-        key = (matrix, solver, str(jnp.dtype(entry.op.dtype)),
-               precond or "", entry.store_dtype,
-               "block" if block else "", bucket)
-        ticket.key = key
-        self.stats["submitted"] += 1
-        queue = self._queues.setdefault(key, _AdmissionQueue())
-        if self.max_queue is not None and len(queue) >= self.max_queue:
-            # explicit rejection instead of unbounded queue growth; the
-            # ticket comes back already terminal, never enqueued
-            ticket._finish("rejected", self.clock())
-            self.stats["rejected"] += 1
+                if solver == "pipelined_cg":
+                    raise NotImplementedError(
+                        "pipelined_cg does not support preconditioning; "
+                        "use solver='cg' with precond=, or drop precond")
+                from repro.solvers.precond import parse_precond_spec
+                kind, param = parse_precond_spec(precond)   # fail at submit,
+                precond = kind if param is None else f"{kind}:{param}"
+            if deadline is not None and not float(deadline) > 0:
+                raise ValueError(
+                    f"deadline must be a positive relative latency target in "
+                    f"seconds, got {deadline!r}")
+            if not float(tol) > 0:
+                raise ValueError(f"tol must be > 0, got {tol!r}")
+            # validate the rhs here: a malformed b discovered at refill time
+            # would already have dequeued (and would lose) sibling requests
+            b = np.asarray(b)
+            if b.ndim != 1 or b.shape[0] != entry.nglobal:
+                raise ValueError(
+                    f"rhs for {matrix!r} must be 1-d of length "
+                    f"{entry.nglobal} (original space), got shape {b.shape}")
+            ticket = SolveTicket(next(self._ids), matrix, solver, b, tol,
+                                 maxiter, precond, deadline=deadline,
+                                 priority=priority, clock=self.clock)
+            # storage dtype, block mode, and (bucketed admission only) the
+            # difficulty bucket are the trailing key components: requests
+            # against f32-stored and bf16-stored matrices never share a
+            # block solve (their compiled matvecs — and their numerics —
+            # differ), block-Krylov batches never mix with column-wise ones
+            # (their stepper states differ), and predicted-short solves
+            # never share a batch with predicted stragglers
+            bucket = ""
+            if self.admission == "bucketed":
+                pred = self.registry.predicted_iters(
+                    matrix, solver=solver, tol=ticket.tol,
+                    maxiter=ticket.maxiter)
+                ticket.pred_iters = pred
+                bucket = f"d{int(math.log(pred, self.bucket_base))}"
+            key = (matrix, solver, str(jnp.dtype(entry.op.dtype)),
+                   precond or "", entry.store_dtype,
+                   "block" if block else "", bucket)
+            ticket.key = key
+            self.stats["submitted"] += 1
+            queue = self._queues.setdefault(key, _AdmissionQueue())
+            if self.max_queue is not None and len(queue) >= self.max_queue:
+                # explicit rejection instead of unbounded queue growth; the
+                # ticket comes back already terminal, never enqueued
+                ticket._finish("rejected", self.clock())
+                self.stats["rejected"] += 1
+                return ticket
+            queue.push(ticket)
             return ticket
-        queue.push(ticket)
-        return ticket
 
     def cancel(self, ticket: SolveTicket) -> bool:
         """Cancel a request.  Returns True iff the cancellation sticks.
@@ -845,27 +849,30 @@ class SolverService:
         advances only it — stragglers no longer tax every other
         request's latency on every round.
         """
-        for key, queue in self._queues.items():
-            if queue and key not in self._batches:
-                self._open_batch(key)
-        if not self._batches:
-            return 0
-        if self.admission == "fifo":
-            keys = list(self._batches)
-        else:
-            picked = self._select_key()
-            keys = [picked] if picked is not None else []
-        chunks = 0
-        for key in keys:
-            batch = self._batches.get(key)
-            if batch is None:
-                continue
-            chunks += self._run_chunk(batch)
-            self._retire_and_refill(batch)
-            if batch.active == 0 and not self._queues.get(key):
-                del self._batches[key]
-                self._age.pop(key, None)
-        return chunks
+        with span("step") as sp:
+            for key, queue in self._queues.items():
+                if queue and key not in self._batches:
+                    self._open_batch(key)
+            if not self._batches:
+                sp.set_metadata(batches=0)
+                return 0
+            if self.admission == "fifo":
+                keys = list(self._batches)
+            else:
+                picked = self._select_key()
+                keys = [picked] if picked is not None else []
+            sp.set_metadata(batches=len(keys))
+            chunks = 0
+            for key in keys:
+                batch = self._batches.get(key)
+                if batch is None:
+                    continue
+                chunks += self._run_chunk(batch)
+                self._retire_and_refill(batch)
+                if batch.active == 0 and not self._queues.get(key):
+                    del self._batches[key]
+                    self._age.pop(key, None)
+            return chunks
 
     def drain(self, max_steps: int = 100_000) -> "deque":
         """Step until every submitted request has been resolved."""
@@ -1035,13 +1042,17 @@ class SolverService:
 
     def _refill(self, batch: _Batch) -> None:
         """Pull queued requests into the batch's free column slots."""
-        if batch.block:
-            self._refill_block(batch)
-            return
+        with span("refill") as sp:
+            admitted = (self._refill_block(batch) if batch.block
+                        else self._refill_columns(batch))
+            sp.set_metadata(admitted=admitted)
+
+    def _refill_columns(self, batch: _Batch) -> int:
+        """Splice queued requests into free columns; returns how many."""
         queue = self._queues.get(batch.key)
         free = [j for j, t in enumerate(batch.slots) if t is None]
         if not queue or not free:
-            return
+            return 0
         op, w = batch.op, batch.width
         dtype = jnp.dtype(op.dtype)
         rdt = jnp.finfo(dtype).dtype               # tolerance dtype
@@ -1062,7 +1073,7 @@ class SolverService:
             tols[j] = ticket.tol
             taken.append((j, ticket))
         if not taken:
-            return
+            return 0
         with self._policy_scope(batch):
             Bop = op.to_op_space(jnp.asarray(Bg))   # one permute per refill
             fresh = batch.init(Bop, jnp.asarray(tols))
@@ -1078,8 +1089,9 @@ class SolverService:
             batch.slots[j] = ticket
             batch.insert_it[j] = block_it
         self.stats["refills"] += 1
+        return len(taken)
 
-    def _refill_block(self, batch: _Batch) -> None:
+    def _refill_block(self, batch: _Batch) -> int:
         """Refill a block-Krylov batch with a warm restart.
 
         Block states carry cross-column ``(b, b)`` Gram/reflection blocks,
@@ -1103,7 +1115,7 @@ class SolverService:
         queue = self._queues.get(batch.key)
         free = [j for j, t in enumerate(batch.slots) if t is None]
         if not queue or not free:
-            return
+            return 0
         op = batch.op
         dtype = jnp.dtype(op.dtype)
         rdt = jnp.finfo(dtype).dtype
@@ -1125,7 +1137,7 @@ class SolverService:
             ticket.status = "running"
             newcomers.append(ticket)
         if not newcomers:
-            return          # nothing admitted (stale queue): keep iterating
+            return 0        # nothing admitted (stale queue): keep iterating
         m = len(survivors) + len(newcomers)
         w = self._pick_width(m, len(queue))
         n0 = np.asarray((survivors[0][1] if survivors
@@ -1154,6 +1166,7 @@ class SolverService:
         for i, ticket in enumerate(newcomers, start=len(survivors)):
             batch.slots[i] = ticket
         self.stats["refills"] += 1
+        return len(newcomers)
 
     def _chunk_k(self, batch: _Batch, now: float) -> int:
         """Iterations for the next chunk, shrunk toward the tightest
@@ -1189,9 +1202,14 @@ class SolverService:
         now = self.clock()
         k = self._chunk_k(batch, now)
         it0 = int(batch.state.it)
-        with self._policy_scope(batch):
-            batch.state = batch.step(batch.op, batch.state, k, M=batch.M)
-        advanced = int(batch.state.it) - it0
+        live = batch.active
+        with span("chunk", k=k, live=live, width=batch.width) as sp:
+            programs = len(chunk_programs(batch.op))
+            with self._policy_scope(batch):
+                batch.state = batch.step(batch.op, batch.state, k, M=batch.M)
+            advanced = int(batch.state.it) - it0
+            sp.set_metadata(advanced=advanced, new_program=int(
+                len(chunk_programs(batch.op)) > programs))
         wall = self.clock() - now
         if wall > 0 and advanced > 0:
             # EWMA of measured per-iteration time feeds deadline slack
@@ -1201,53 +1219,58 @@ class SolverService:
             batch.est_iter_s = (per_iter if batch.est_iter_s is None
                                 else 0.7 * batch.est_iter_s + 0.3 * per_iter)
         self.stats["chunks"] += 1
+        self.stats["iters"] += advanced
+        self.stats["column_iters"] += advanced * batch.width
+        self.stats["live_column_iters"] += advanced * live
         return 1
 
     def _retire_and_refill(self, batch: _Batch) -> None:
         if batch.state is None:
             self._refill(batch)
             return
-        now = self.clock()
-        state = batch.state
-        done = np.asarray(state.done)
-        block_it = int(state.it)
-        # (slot, ticket, spent, status) for tickets that get a result;
-        # cancellations resolve without one.  Cancellation wins over a
-        # convergence observed at the same boundary (cancel() promised).
-        retiring: List[Tuple[int, SolveTicket, int, str]] = []
-        for j, ticket in enumerate(batch.slots):
-            if ticket is None:
-                continue
-            spent = block_it - batch.insert_it[j]
-            if ticket._cancel_requested:
-                batch.slots[j] = None
-                ticket._finish("cancelled", now)
-                self.completed.append(ticket)
-                self.stats["cancelled"] += 1
-            elif done[j] or spent >= ticket.maxiter:
-                retiring.append((j, ticket, spent, "done"))
-            elif ticket.deadline is not None and now >= ticket.deadline:
-                # running past its deadline: retire with the best-effort
-                # iterate (column and block batches alike)
-                retiring.append((j, ticket, spent, "expired"))
-        if retiring:
-            res = batch.finalize(state)              # one readout per sweep
-            # an index array, not a list: JAX retraces list indexing
-            idx = np.asarray([j for j, _, _, _ in retiring])
-            xs = np.asarray(batch.op.from_op_space(res.x[:, idx]))
-            resn = np.asarray(res.resnorm)
-            for m, (j, ticket, spent, status) in enumerate(retiring):
-                ticket.result = ServiceResult(
-                    x=xs[:, m], iters=spent, resnorm=float(resn[j]),
-                    converged=bool(done[j]))
-                ticket._finish(status, now)
-                batch.slots[j] = None
-                self.completed.append(ticket)
-                if status == "done":
-                    self.stats["retired"] += 1
-                    self.stats["converged"] += int(done[j])
-                else:
-                    self.stats["expired"] += 1
+        with span("retire") as sp:
+            now = self.clock()
+            state = batch.state
+            done = np.asarray(state.done)
+            block_it = int(state.it)
+            # (slot, ticket, spent, status) for tickets that get a result;
+            # cancellations resolve without one.  Cancellation wins over a
+            # convergence observed at the same boundary (cancel() promised).
+            retiring: List[Tuple[int, SolveTicket, int, str]] = []
+            for j, ticket in enumerate(batch.slots):
+                if ticket is None:
+                    continue
+                spent = block_it - batch.insert_it[j]
+                if ticket._cancel_requested:
+                    batch.slots[j] = None
+                    ticket._finish("cancelled", now)
+                    self.completed.append(ticket)
+                    self.stats["cancelled"] += 1
+                elif done[j] or spent >= ticket.maxiter:
+                    retiring.append((j, ticket, spent, "done"))
+                elif ticket.deadline is not None and now >= ticket.deadline:
+                    # running past its deadline: retire with the best-effort
+                    # iterate (column and block batches alike)
+                    retiring.append((j, ticket, spent, "expired"))
+            if retiring:
+                res = batch.finalize(state)          # one readout per sweep
+                # an index array, not a list: JAX retraces list indexing
+                idx = np.asarray([j for j, _, _, _ in retiring])
+                xs = np.asarray(batch.op.from_op_space(res.x[:, idx]))
+                resn = np.asarray(res.resnorm)
+                for m, (j, ticket, spent, status) in enumerate(retiring):
+                    ticket.result = ServiceResult(
+                        x=xs[:, m], iters=spent, resnorm=float(resn[j]),
+                        converged=bool(done[j]))
+                    ticket._finish(status, now)
+                    batch.slots[j] = None
+                    self.completed.append(ticket)
+                    if status == "done":
+                        self.stats["retired"] += 1
+                        self.stats["converged"] += int(done[j])
+                    else:
+                        self.stats["expired"] += 1
+            sp.set_metadata(retired=len(retiring))
         self._refill(batch)
 
     # ------------------------------------------- spectral (KPM/ChebFD) side

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.spmv import SpmvOpts, as2d
+from repro.core.trace import scope
 from repro.solvers.block import BlockCGState, block_cg_body, block_cg_init
 from repro.solvers.stepper import run_chunk
 
@@ -108,16 +109,18 @@ def _colsum(v):
     expression is character-identical to PR 3, preserving the pinned
     bit-identity of every real solve.
     """
-    if jnp.iscomplexobj(v):
-        return jnp.sum((jnp.conj(v) * v).real, axis=0)
-    return jnp.sum(v * v, axis=0)
+    with scope("dots"):
+        if jnp.iscomplexobj(v):
+            return jnp.sum((jnp.conj(v) * v).real, axis=0)
+        return jnp.sum(v * v, axis=0)
 
 
 def _inner(a, b):
     """Per-column <a, b> with the conjugate-linear first argument."""
-    if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
-        return jnp.sum(jnp.conj(a) * b, axis=0)
-    return jnp.sum(a * b, axis=0)
+    with scope("dots"):
+        if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
+            return jnp.sum(jnp.conj(a) * b, axis=0)
+        return jnp.sum(a * b, axis=0)
 
 
 def _maybe_1d(res: CGResult, was1d: bool) -> CGResult:

@@ -29,8 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import scope
+
 __all__ = ["run_chunk", "merge_columns", "merge_columns_masked",
-           "snap_chunk", "clear_chunk_cache"]
+           "snap_chunk", "clear_chunk_cache", "chunk_programs"]
 
 # op -> {(solver_name, k): jitted chunk}; weak so dropping an operator
 # (e.g. a registry eviction) frees its compiled chunks too
@@ -84,12 +86,23 @@ def run_chunk(op, name: str, k: int, state, body: Callable, *,
                 i, s = carry
                 return i + 1, body(o, s)
 
-            _, out = jax.lax.while_loop(cond, step, (jnp.asarray(0), st))
+            with scope("chunk"):
+                _, out = jax.lax.while_loop(cond, step,
+                                            (jnp.asarray(0), st))
             return out
 
         fn = jax.jit(chunk)
         per_op[cache_key] = fn
     return fn(state)
+
+
+def chunk_programs(op) -> dict:
+    """The jitted chunks cached for ``op``, by ``(solver, k)`` (with a
+    weak reference to ``extra_key`` third where one was given).  Each
+    compiles (or loads from the compile cache) at its first call;
+    lowered with a state, its compiled HLO text names each device
+    operation's ``ghost.*`` scope in the ``op_name`` metadata."""
+    return dict(_chunk_cache.get(op, {}))
 
 
 def snap_chunk(k, k_max: int) -> int:
