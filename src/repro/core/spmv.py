@@ -10,8 +10,12 @@ Every augmentation is individually switchable, exactly like the paper's
 ``GHOST_SPMV_*`` flags.  ``x``/``y``/``z`` may be single vectors ``(n,)`` or
 block vectors ``(n, b)`` (row-major interleaved storage — paper section 5.2).
 
-Two executors:
-  * ``impl='ref'``     — pure jnp (segment-sum) oracle, runs anywhere.
+Three executors of the product ``A x``; the augmentation is shared
+(:func:`augment`):
+  * ``impl='ref'``     — pure jnp (gather + row sums) oracle, runs anywhere.
+  * ``impl='window'``  — the product by ``kernels.sellcs_window`` (``x``
+    held in VMEM, lane gathers per column block), the TPU default for a
+    qualifying matrix (``GhostOperator(impl="auto")``).
   * ``impl='pallas'``  — the SELL-C-sigma Pallas TPU kernel (fused sweep).
 
 All vectors live in the matrix' *permuted* space of length ``nrows_pad``
@@ -30,7 +34,7 @@ from repro.core import blockvec
 from repro.core.sellcs import SellCS
 from repro.core.trace import scope
 
-__all__ = ["SpmvOpts", "as2d", "pack_coefs", "spmv", "spmv_ref",
+__all__ = ["SpmvOpts", "as2d", "augment", "pack_coefs", "spmv", "spmv_ref",
            "dot_acc_dtype", "storage_acc_dtype", "compensated_sum0",
            "fused_dots"]
 
@@ -180,6 +184,13 @@ def fused_dots(x2: jax.Array, y2: jax.Array, opts: SpmvOpts) -> jax.Array:
     return dots
 
 
+def _check_x(A: SellCS, x2: jax.Array) -> None:
+    if x2.shape[0] != A.nrows_pad:
+        raise ValueError(
+            f"spmv: x must be permuted/padded to {A.nrows_pad} rows, "
+            f"got {x2.shape}")
+
+
 def spmv_ref(
     A: SellCS,
     x: jax.Array,
@@ -193,17 +204,26 @@ def spmv_ref(
     None if no dot was requested.  z is None unless chaining was requested.
     """
     x2, was1d = _as2d(x)
+    _check_x(A, x2)
     n = A.nrows_pad
-    if x2.shape[0] != n:
-        raise ValueError(
-            f"spmv: x must be permuted/padded to {n} rows, got {x2.shape}")
     # accumulate in the matrix' *compute* dtype (== vals dtype for single-
     # dtype matrices — that leg is bit-identical to the classic layout);
     # a narrower store_dtype upcasts per-element before the products
     acc_dt = jnp.result_type(A.dtype, x2.dtype)
     contrib = A.vals.astype(acc_dt)[:, None] * x2.astype(acc_dt)[A.cols]
     Ax = row_sums(contrib, A.rowids, n, A.C, A.uniform_width)
+    return augment(Ax, x2, was1d, y, z, opts)
 
+
+def augment(Ax: jax.Array, x2: jax.Array, was1d: bool,
+            y: Optional[jax.Array], z: Optional[jax.Array],
+            opts: SpmvOpts):
+    """The fused SpMV's augmentation of the product ``Ax`` (n, b):
+    ``alpha (Ax - gamma x) + beta y``, the chained axpby and the fused
+    dots.  Shared by every executor of the product, so they differ only
+    in how ``Ax`` is computed.  Returns (y, z, dots) as :func:`spmv_ref`.
+    """
+    acc_dt = Ax.dtype
     if opts.gamma is not None:
         gamma = jnp.asarray(opts.gamma)
         Ax = Ax - gamma * x2                          # (A - gamma I) x
@@ -260,16 +280,29 @@ def spmv(
     *,
     impl: str = "ref",
     interpret: Optional[bool] = None,
+    window=None,
 ):
     """Dispatching fused SpMV (GHOST single-interface ``ghost_spmv``).
 
     ``interpret=None`` defers to :mod:`repro.core.execution` (compiled on
     TPU, interpret elsewhere, env/context overridable).  Its device work
     is named ``ghost.spmv`` (the fused dots inside it ``ghost.dots``).
+    ``impl="window"`` runs the product with the block lists ``window``
+    (:func:`repro.kernels.sellcs_window.plan`) where ``x`` fits the
+    kernel (:func:`~repro.kernels.sellcs_window.fits`), else as ``ref``.
     """
     with scope("spmv"):
         if impl == "ref":
             return spmv_ref(A, x, y, z, opts)
+        if impl == "window":
+            from repro.kernels import sellcs_window
+            x2, was1d = _as2d(x)
+            _check_x(A, x2)
+            if not sellcs_window.fits(A.nrows_pad, x2.shape[1], x2.dtype):
+                return spmv_ref(A, x, y, z, opts)
+            Ax = sellcs_window.window_product(A, x2, window,
+                                              interpret=interpret)
+            return augment(Ax, x2, was1d, y, z, opts)
         if impl == "pallas":
             from repro.kernels import ops as kops
             return kops.sellcs_spmv(A, x, y, z, opts, interpret=interpret)

@@ -141,6 +141,9 @@ class _Entry:
     bounds: Optional[Tuple[float, float]] = None
     ritz: Optional[np.ndarray] = None     # raw Ritz values of the one run
     preconds: dict = dataclasses.field(default_factory=dict)  # spec -> M
+    spmv_path: str = ""               # the product's resolved executor
+    # column blocks per chunk (mean, max) of the windowed kernel's lists
+    blocks_per_chunk: Optional[Tuple[float, int]] = None
 
 
 def _resolved_store_dtype(vals, dtype, store_dtype) -> str:
@@ -194,14 +197,15 @@ class MatrixRegistry:
         self._entries: Dict[str, _Entry] = {}
         self.stats = {"builds": 0, "hits": 0,
                       "bounds_computed": 0, "bounds_hits": 0,
-                      "precond_builds": 0, "precond_hits": 0}
+                      "precond_builds": 0, "precond_hits": 0,
+                      "spmv_window": 0}
 
     # -------------------------------------------------------------- admin
     def register(self, name: str, matrix=None, *,
                  rows=None, cols=None, vals=None, shape=None,
                  C: int = 32, sigma: int = 1, w_align: int = 1, dtype=None,
                  store_dtype=None,
-                 impl: str = "ref", interpret: Optional[bool] = None,
+                 impl: str = "auto", interpret: Optional[bool] = None,
                  autotune_tiles: bool = False) -> str:
         """Register a matrix under ``name`` (idempotent — reuse is a hit).
 
@@ -217,6 +221,15 @@ class MatrixRegistry:
         see :func:`repro.core.sellcs.from_coo`) and is part of the matrix
         identity — the same COO data at two storage widths must be two
         registrations, and their requests batch separately.
+
+        ``impl`` picks how a SELL-C-sigma matrix's product runs
+        (:class:`~repro.solvers.operator.GhostOperator`): ``"auto"``
+        takes the windowed Pallas kernel where it qualifies (see
+        ``docs/execution.md``), else the jnp product.  The entry records
+        the resolved path (``spmv_path``) and the kernel's column blocks
+        per chunk (``blocks_per_chunk``, mean and max);
+        ``stats["spmv_window"]`` counts the registrations whose product
+        runs the kernel.
 
         Re-registering a name with the *same* payload is a cache hit;
         with a different matrix (different COO bytes *or* a different
@@ -284,11 +297,16 @@ class MatrixRegistry:
                 (256, 512, 1024), _run,
                 dtype=(sdt, str(jnp.dtype(op.dtype))))
             tuned = {"row_tile": int(best)}
+        window = getattr(op, "window", None)
         self._entries[name] = _Entry(
             name=name, matrix=matrix, op=op, nglobal=int(nglobal),
             build_seconds=time.perf_counter() - t0, tuned=tuned,
-            store_dtype=sdt, fingerprint=fingerprint)
+            store_dtype=sdt, fingerprint=fingerprint,
+            spmv_path=str(getattr(op, "impl", "")),
+            blocks_per_chunk=(None if window is None else
+                              (window.mean_blocks, window.maxb)))
         self.stats["builds"] += 1
+        self.stats["spmv_window"] += window is not None
         return name
 
     def __contains__(self, name: str) -> bool:

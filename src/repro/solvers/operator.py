@@ -24,14 +24,28 @@ import numpy as np
 
 from repro.core.sellcs import SellCS
 from repro.core.spmv import SpmvOpts, as2d, fused_dots, pack_coefs, spmv
+from repro.kernels import sellcs_window
 
 
 class GhostOperator:
-    def __init__(self, A: SellCS, *, impl: str = "ref",
+    """A SELL-C-sigma matrix as a solver operator.
+
+    ``impl="auto"`` resolves once, here, to ``"window"`` (the Pallas
+    kernel of :mod:`repro.kernels.sellcs_window`, whose block lists are
+    built now) where that kernel qualifies for ``A``, else to ``"ref"``;
+    ``"ref"`` and ``"pallas"`` are taken as given.  ``window`` holds the
+    kernel's plan, or None.
+    """
+
+    def __init__(self, A: SellCS, *, impl: str = "auto",
                  interpret: Optional[bool] = None):
         # interpret=None defers to repro.core.execution at call time, so
         # the operator follows `execution.force(...)` scopes automatically
         self.A = A
+        self.window = None
+        if impl == "auto":
+            self.window = sellcs_window.plan(A, interpret)
+            impl = "ref" if self.window is None else "window"
         self.impl = impl
         self.interpret = interpret
         self.n = A.nrows_pad
@@ -41,12 +55,13 @@ class GhostOperator:
         self.store_dtype = A.store_dtype
 
     def mv(self, x: jax.Array) -> jax.Array:
-        y, _, _ = spmv(self.A, x, impl=self.impl, interpret=self.interpret)
+        y, _, _ = spmv(self.A, x, impl=self.impl, interpret=self.interpret,
+                       window=self.window)
         return y
 
     def mv_fused(self, x, y=None, z=None, opts: SpmvOpts = SpmvOpts()):
         return spmv(self.A, x, y, z, opts, impl=self.impl,
-                    interpret=self.interpret)
+                    interpret=self.interpret, window=self.window)
 
     def to_op_space(self, v):
         return self.A.permute(v)
@@ -116,7 +131,8 @@ class DistOperator:
                  interpret: Optional[bool] = None):
         self.engine = engine
         self.overlap = overlap
-        self.impl = impl
+        # the shards take an explicit executor; "auto" is the jnp product
+        self.impl = "ref" if impl == "auto" else impl
         self.interpret = interpret
         self._mask_cache = (None, None)     # (A object, its mask)
 

@@ -81,3 +81,48 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_window_spmv_compiles_for_v5e(one_chip, monkeypatch):
+    """The windowed SpMV as CG calls it (``dot_xy``), on the benchmark
+    cell's own matrix: laplace3d(128) in SELL-128-1, 8 slots wide, whose
+    chunks list 5 column blocks.  The product is one Mosaic kernel: no
+    XLA gather, ``x`` and ``y`` pass in and out as bitcasts of the
+    lane-dense (n, 8) layout (no transpose or copy), and the 537 MB
+    gather temporary is gone."""
+    import dataclasses
+    import re
+
+    from repro.core import execution
+    from repro.core.sellcs import from_coo
+    from repro.core.spmv import SpmvOpts, spmv
+    from repro.kernels import sellcs_window
+    from repro.matrices.generators import laplace3d
+    from repro.solvers.operator import GhostOperator
+
+    monkeypatch.setattr(sellcs_window, "compiled",
+                        lambda interpret=None: True)
+    r, c, v, n = laplace3d(128)
+    A = from_coo(r, c, v, (n, n), C=128, w_align=8, dtype=F32)
+    del r, c, v
+    op = GhostOperator(A)
+    assert n == N and op.impl == "window" and op.window.maxb == 5
+
+    def cg_product(A, lists, x):
+        plan = dataclasses.replace(op.window, lists=lists)
+        return spmv(A, x, opts=SpmvOpts(dot_xy=True), impl=op.impl,
+                    window=plan)
+
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (A, op.window.lists))
+    x = jax.ShapeDtypeStruct((N, 8), F32, sharding=one_chip)
+    with execution.force(interpret=False):
+        compiled = jax.jit(cg_product).lower(*shapes, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\bgather\(", text)
+    for shape in (f"{N},8", f"8,{N}"):
+        assert not re.search(rf"= f32\[{shape}\]\S* (transpose|copy)\(",
+                             text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
