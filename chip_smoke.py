@@ -147,8 +147,11 @@ def one_chip(nx: int, seed: int, cache: CacheEvents) -> None:
     reg = MatrixRegistry()
     reg.register(MATRIX, rows=r, cols=c, vals=v, shape=(n, n), C=128,
                  sigma=1, w_align=8, dtype=np.float32)
+    e = reg.entry(MATRIX)
     emit(phase="setup", n=int(n), nnz=int(len(v)), generate_s=generate_s,
-         register_s=time.perf_counter() - t0)
+         register_s=time.perf_counter() - t0, spmv_path=e.spmv_path,
+         steps_per_chunk=e.steps_per_chunk,
+         slot_rows=reg.stats["spmv_window_slot_rows"])
 
     svc = SolverService(reg, block_width=8)
     rng = np.random.default_rng(seed)

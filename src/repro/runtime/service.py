@@ -144,6 +144,9 @@ class _Entry:
     spmv_path: str = ""               # the product's resolved executor
     # column blocks per chunk (mean, max) of the windowed kernel's lists
     blocks_per_chunk: Optional[Tuple[float, int]] = None
+    # the kernel's steps a chunk at b > 1 in the walk its plan chose,
+    # and in the block rounds (the same where it walks block rounds)
+    steps_per_chunk: Optional[Tuple[float, float]] = None
 
 
 def _resolved_store_dtype(vals, dtype, store_dtype) -> str:
@@ -198,7 +201,7 @@ class MatrixRegistry:
         self.stats = {"builds": 0, "hits": 0,
                       "bounds_computed": 0, "bounds_hits": 0,
                       "precond_builds": 0, "precond_hits": 0,
-                      "spmv_window": 0}
+                      "spmv_window": 0, "spmv_window_slot_rows": 0}
 
     # -------------------------------------------------------------- admin
     def register(self, name: str, matrix=None, *,
@@ -227,9 +230,11 @@ class MatrixRegistry:
         takes the windowed Pallas kernel where it qualifies (see
         ``docs/execution.md``), else the jnp product.  The entry records
         the resolved path (``spmv_path``) and the kernel's column blocks
-        per chunk (``blocks_per_chunk``, mean and max);
+        per chunk (``blocks_per_chunk``, mean and max) and its steps a
+        chunk at b > 1 against the block rounds' (``steps_per_chunk``);
         ``stats["spmv_window"]`` counts the registrations whose product
-        runs the kernel.
+        runs the kernel, ``stats["spmv_window_slot_rows"]`` those of
+        them that walk slot rows.
 
         Re-registering a name with the *same* payload is a cache hit;
         with a different matrix (different COO bytes *or* a different
@@ -304,9 +309,13 @@ class MatrixRegistry:
             store_dtype=sdt, fingerprint=fingerprint,
             spmv_path=str(getattr(op, "impl", "")),
             blocks_per_chunk=(None if window is None else
-                              (window.mean_blocks, window.maxb)))
+                              (window.mean_blocks, window.maxb)),
+            steps_per_chunk=(None if window is None else
+                             (window.steps, window.rounds_steps)))
         self.stats["builds"] += 1
         self.stats["spmv_window"] += window is not None
+        self.stats["spmv_window_slot_rows"] += (
+            window is not None and window.layout == "slot_rows")
         return name
 
     def __contains__(self, name: str) -> bool:

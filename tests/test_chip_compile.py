@@ -83,46 +83,66 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             + mem.output_size_in_bytes) < HBM_BYTES
 
 
-def test_window_spmv_compiles_for_v5e(one_chip, monkeypatch):
-    """The windowed SpMV as CG calls it (``dot_xy``), on the benchmark
-    cell's own matrix: laplace3d(128) in SELL-128-1, 8 slots wide, whose
-    chunks list 5 column blocks.  The product is one Mosaic kernel: no
-    XLA gather, ``x`` and ``y`` pass in and out as bitcasts of the
-    lane-dense (n, 8) layout (no transpose or copy), and the 537 MB
-    gather temporary is gone."""
-    import dataclasses
-    import re
-
-    from repro.core import execution
+@pytest.fixture(scope="module")
+def cell_operator():
+    """The benchmark cell's matrix, laplace3d(128) in SELL-128-1, 8 slots
+    wide, and its operator as the chip resolves it."""
     from repro.core.sellcs import from_coo
-    from repro.core.spmv import SpmvOpts, spmv
     from repro.kernels import sellcs_window
     from repro.matrices.generators import laplace3d
     from repro.solvers.operator import GhostOperator
 
-    monkeypatch.setattr(sellcs_window, "compiled",
-                        lambda interpret=None: True)
     r, c, v, n = laplace3d(128)
     A = from_coo(r, c, v, (n, n), C=128, w_align=8, dtype=F32)
     del r, c, v
-    op = GhostOperator(A)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sellcs_window, "compiled", lambda interpret=None: True)
+        op = GhostOperator(A)
     assert n == N and op.impl == "window" and op.window.maxb == 5
+    return A, op
+
+
+@pytest.mark.parametrize("walk,b", [("slot_rows", 8), ("block_rounds", 8),
+                                    ("block_rounds", 1)])
+def test_window_spmv_compiles_for_v5e(one_chip, cell_operator, walk, b):
+    """The windowed SpMV as CG calls it (``dot_xy``), on the benchmark
+    cell's own matrix, whose chunks list 5 column blocks and walk their
+    slot rows in 11 steps, in each walk of a chunk: the slot rows its
+    plan takes at b = 8, the block rounds forced at b = 8, and the block
+    rounds of b = 1.  The product is one Mosaic kernel: no XLA gather,
+    ``x`` and ``y`` pass in and out as bitcasts of the lane-dense (n, b)
+    layout (no transpose or copy), and the 537 MB gather temporary is
+    gone.  The lists, a constant of the programs, stay within 2 MB."""
+    import dataclasses
+    import re
+
+    from repro.core import execution
+    from repro.core.spmv import SpmvOpts, spmv
+
+    A, op = cell_operator
+    p = op.window
+    assert p.layout == "slot_rows" and p.steps == 11.0
+    field = "row_lists" if walk == "slot_rows" else "lists"
+    if b > 1 and walk == "block_rounds":
+        p = dataclasses.replace(p, row_lists=None)
+    lists = getattr(p, field)
+    assert lists.nbytes <= 2 << 20
 
     def cg_product(A, lists, x):
-        plan = dataclasses.replace(op.window, lists=lists)
+        plan = dataclasses.replace(p, **{field: lists})
         return spmv(A, x, opts=SpmvOpts(dot_xy=True), impl=op.impl,
                     window=plan)
 
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (A, op.window.lists))
-    x = jax.ShapeDtypeStruct((N, 8), F32, sharding=one_chip)
+        (A, lists))
+    x = jax.ShapeDtypeStruct((N, b), F32, sharding=one_chip)
     with execution.force(interpret=False):
         compiled = jax.jit(cg_product).lower(*shapes, x).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"\bgather\(", text)
-    for shape in (f"{N},8", f"8,{N}"):
+    for shape in (f"{N},{b}", f"{b},{N}"):
         assert not re.search(rf"= f32\[{shape}\]\S* (transpose|copy)\(",
                              text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6

@@ -69,9 +69,31 @@ def _ragged_group(rng):
     return _coo(r, c, n, rng)
 
 
+def _wide_few(rng):
+    """64 chunks 8 slots wide: chunk 0's slot rows each span 16 column
+    blocks, every other chunk's rows lie in its own block.  Every chunk
+    would pay chunk 0's 16 blocks a slot row (128 steps) on the slot
+    rows; on block rounds only chunk 0 takes more than one round."""
+    n = 64 * 128
+    r = np.repeat(np.arange(n), 8)
+    own = (r // 128) * 128 + np.concatenate(
+        [rng.choice(128, 8, replace=False) for _ in range(n)])
+    # row l of chunk 0: one column in each of blocks l % 16 ... l % 16 + 7
+    spread = (r % 16 + np.tile(np.arange(8), n)) * 128 + r % 128
+    return _coo(r, np.where(r < 128, spread, own), n, rng)
+
+
 MATRICES = {"one_block": (_one_block, 16), "many_blocks": (_many_blocks, 8),
             "empty_rows": (_empty_rows, 16), "padding": (_laplace, 8),
-            "ragged_group": (_ragged_group, 32)}
+            "ragged_group": (_ragged_group, 32), "wide_few": (_wide_few, 8)}
+
+#: the layout ``plan`` takes for each matrix at b > 1, and why
+LAYOUTS = {"one_block": "block_rounds",       # 16 steps a chunk either way
+           "many_blocks": "slot_rows",        # 51 steps against 64
+           "empty_rows": "slot_rows",         # 44 against 64
+           "padding": "slot_rows",            # 11 against 24
+           "ragged_group": "block_rounds",    # 415 steps: too long to unroll
+           "wide_few": "block_rounds"}        # 128 against 66
 
 
 def _matrix(name, seed=0):
@@ -95,17 +117,34 @@ def _opts(name, b, rng):
     }[name]
 
 
-PARITY = ([(m, b, "plain") for m in MATRICES for b in (1, 8)]
-          + [("padding", 8, o) for o in ("cg_dot_xy", "precond_alpha_beta",
-                                         "kpm_shift_dots", "chain_axpby")]
-          + [("padding", 1, "kpm_shift_dots")])
+# (matrix, b, opts, layout): layout None is the one plan chooses; the
+# slot-rows cases build that layout where plan would not take it
+PARITY = ([(m, b, "plain", None) for m in MATRICES for b in (1, 8)]
+          + [("padding", 8, o, None)
+             for o in ("cg_dot_xy", "precond_alpha_beta", "kpm_shift_dots",
+                       "chain_axpby")]
+          + [("padding", 1, "kpm_shift_dots", None)]
+          + [(m, 8, "plain", "slot_rows") for m in MATRICES
+             if LAYOUTS[m] != "slot_rows"])
 
 
-@pytest.mark.parametrize("matrix,b,opts", PARITY)
-def test_window_matches_ref(on_tpu, matrix, b, opts):
+@pytest.mark.parametrize("matrix,b,opts,layout", [
+    pytest.param(*case, id="-".join(str(a) for a in case if a is not None))
+    for case in PARITY])
+def test_window_matches_ref(on_tpu, monkeypatch, matrix, b, opts, layout):
     A = _matrix(matrix)
     p = sw.plan(A)
-    assert p is not None
+    assert p is not None and p.layout == LAYOUTS[matrix]
+    if layout is not None:
+        monkeypatch.setattr(sw, "_layout", lambda *steps: layout)
+        p = sw.plan(A)
+        assert p.layout == layout
+    walks, kernel = set(), sw._kernel
+
+    def spy(*args, **kw):
+        walks.add(kw["layout"])
+        return kernel(*args, **kw)
+    monkeypatch.setattr(sw, "_kernel", spy)
     rounds = np.asarray(p.lists)[:, 0, ::p.row].ravel()[:p.nchunks]
     if matrix == "one_block":
         assert p.maxb == 1
@@ -115,6 +154,9 @@ def test_window_matches_ref(on_tpu, matrix, b, opts):
         assert rounds[1] == 0
     if matrix == "ragged_group":
         assert p.nchunks % p.group
+    if matrix == "wide_few":
+        assert p.maxb == 23 and p.unroll == 8
+        assert rounds[0] == 3 and (rounds[1:] == 1).all()
     rng = np.random.default_rng(1)
     o = _opts(opts, b, rng)
     n = A.nrows_pad
@@ -133,6 +175,8 @@ def test_window_matches_ref(on_tpu, matrix, b, opts):
             assert g.shape == w.shape
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-5, atol=2e-5)
+    # b = 1 walks block rounds whatever the plan
+    assert walks == {"block_rounds" if b == 1 else p.layout}
 
 
 def test_block_lists_leave_out_padding_slots():
@@ -161,6 +205,27 @@ def test_block_lists_build_is_fast_at_full_size():
     counts, blocks = sw.block_lists(cols.ravel(), row_len, W)
     assert time.perf_counter() - t < 3.0
     assert counts.max() <= 5
+
+
+def test_plan_of_the_cells_matrix_walks_slot_rows(on_tpu):
+    """laplace3d(128) in SELL-128-1, 8 slots wide: slot rows 4 and 5
+    span two blocks only at the lanes whose x-neighbour is missing, and
+    slot row 7 is all padding, so a chunk takes 11 steps against the
+    block rounds' 40; the plan is built within the block lists' limit."""
+    import time
+    r, c, v, n = laplace3d(128)
+    A = from_coo(r, c, v, (n, n), C=128, w_align=8, dtype=np.float32)
+    del r, c, v
+    t = time.perf_counter()
+    p = sw.plan(A)
+    assert time.perf_counter() - t < 3.0
+    assert p.layout == "slot_rows" and p.maxb == 5
+    assert p.row_unroll == (1, 1, 2, 2, 2, 2, 1, 0)
+    assert p.steps == 11.0 and p.rounds_steps == 40.0
+    counts, _ = sw.row_block_lists(np.asarray(A.cols),
+                                   np.asarray(A.row_len), A.uniform_width)
+    assert counts.sum() == 146_688
+    assert p.row_lists.nbytes <= 2 << 20
 
 
 # --------------------------------------------------------- auto resolution
@@ -195,7 +260,7 @@ def test_auto_resolves_to_ref(on_tpu, monkeypatch, case):
     elif case == "bf16_store":
         kw["store_dtype"] = jnp.bfloat16
     elif case == "above_break_even":
-        monkeypatch.setattr(sw, "BREAK_EVEN_BLOCKS", 2)
+        monkeypatch.setattr(sw, "BREAK_EVEN_STEPS", 1)
     elif case == "explicit_ref":
         impl = "ref"
     A = from_coo(r, c, v, (n, n), **kw)
@@ -231,13 +296,17 @@ def test_registry_records_the_resolved_path(on_tpu):
     reg.register("ref", rows=r, cols=c, vals=v, shape=(n, n), C=128,
                  w_align=8, dtype=np.float32, impl="ref")
     assert reg.stats["spmv_window"] == 1
+    assert reg.stats["spmv_window_slot_rows"] == 1
     e = reg.entry("win")
     assert e.spmv_path == "window"
     mean, mx = e.blocks_per_chunk
     assert mx == 3 and 1 <= mean <= 3          # 512 rows: 4 chunks
+    # slot rows (2,1,1,1,2,2,2,0) against one round of 3 blocks x 8
+    assert e.steps_per_chunk == (11.0, 24.0)
     for name in ("ragged", "ref"):
         assert reg.entry(name).spmv_path == "ref"
         assert reg.entry(name).blocks_per_chunk is None
+        assert reg.entry(name).steps_per_chunk is None
 
 
 def test_dist_operator_auto_is_ref():
